@@ -43,6 +43,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated module keys")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     keys = args.only.split(",") if args.only else list(MODULES)
     print("name,us_per_call,derived")
     t0 = time.monotonic()

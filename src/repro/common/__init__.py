@@ -13,6 +13,7 @@ table we derive:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Mapping
 
@@ -61,7 +62,18 @@ class ParamSpec:
             scale = self.init_scale / math.sqrt(max(fan_in, 1))
         else:  # pragma: no cover - guarded by tests
             raise ValueError(f"unknown init {self.init}")
-        return (scale * jax.random.normal(key, self.shape, jnp.float32)).astype(self.dtype)
+        return _draw_normal(key, self.shape, jnp.dtype(self.dtype), jnp.float32(scale))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _draw_normal(key, shape, dtype, scale):
+    """``scale * N(0, 1)`` cast to ``dtype``, as one program: a leaf costs its
+    float32 draw as a temporary beside its output, where the eager ops keep
+    the draw, its scaled copy and the cast alive at once (5.6 GB for a
+    28 x 3072 x 8192 stack).  The barrier keeps the multiply out of the
+    draw's fusion, so the values are bit for bit those of the eager ops."""
+    x = jax.lax.optimization_barrier(jax.random.normal(key, shape, jnp.float32))
+    return (scale * x).astype(dtype)
 
 
 SpecTree = dict[Path, ParamSpec]

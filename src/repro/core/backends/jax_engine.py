@@ -39,12 +39,14 @@ class EngineModel:
 
 
 def make_session(oracle_cfg: ModelConfig | None = None,
-                 proxy_cfg: ModelConfig | None = None, *,
+                 proxy_cfg: ModelConfig | None = None,
+                 embed_cfg: ModelConfig | None = None, *,
                  max_seq: int = 512, seed: int = 0, **session_kw) -> Session:
     """Build a full-JAX Session: oracle + proxy engines + encoder embedder.
 
     Defaults mirror the paper's pipeline shape at smoke scale: a larger
-    oracle (llama-family) and a smaller proxy (the Llama-8B/TinyLlama role).
+    oracle (llama-family), a smaller proxy (the Llama-8B/TinyLlama role) and
+    a 2-layer encoder; pass published configs to run at real widths.
     """
     oracle_cfg = oracle_cfg or get_smoke("llama3.2-3b").with_(
         vocab_size=TOKENIZER.vocab_size, num_layers=4, d_model=128, d_ff=256)
@@ -52,6 +54,7 @@ def make_session(oracle_cfg: ModelConfig | None = None,
         vocab_size=TOKENIZER.vocab_size, num_layers=2, d_model=64, d_ff=128)
     oracle = EngineModel(InferenceEngine(oracle_cfg, max_seq=max_seq, seed=seed))
     proxy = EngineModel(InferenceEngine(proxy_cfg, max_seq=max_seq, seed=seed + 1))
-    embedder = Embedder(E5_SMALL.with_(num_layers=2, d_model=64, num_heads=4,
-                                       num_kv_heads=4, d_ff=128), seed=seed + 2)
+    embed_cfg = embed_cfg or E5_SMALL.with_(num_layers=2, d_model=64, num_heads=4,
+                                            num_kv_heads=4, d_ff=128)
+    embedder = Embedder(embed_cfg, seed=seed + 2)
     return Session(oracle=oracle, proxy=proxy, embedder=embedder, **session_kw)

@@ -14,10 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models.attention import NEG_INF, _repeat_kv, out_proj, project_qkv
 
@@ -75,5 +72,5 @@ def cp_decode_self_attention(params, x, k_cache, v_cache, cache_len, *,
         body, mesh=mesh,
         in_specs=(P(), bat_spec, kv_spec, kv_spec, bat_spec, P(axis)),
         out_specs=(bat_spec, kv_spec, kv_spec),
-        check_rep=False)(params, x, k_cache, v_cache, lens, pos)
+        check_vma=False)(params, x, k_cache, v_cache, lens, pos)
     return out_proj(params, attn), kc, vc

@@ -14,10 +14,6 @@ each logical axis to an ordered tuple of *candidate* mesh axes, and
 
 ``activation_rules`` installs a (mesh, rules) context consumed by
 ``shard_activation`` inside model code — the models never mention mesh axes.
-
-Version compat: this repo runs against jax>=0.4.37; ``abstract_mesh`` /
-``set_mesh`` paper over the AbstractMesh-constructor and ambient-mesh API
-changes between 0.4.x and 0.5+ so tests and launch scripts are portable.
 """
 from __future__ import annotations
 
@@ -154,27 +150,3 @@ def shard_activation(x, axes):
     mesh, rules = ctx
     spec = resolve_pspec(x.shape, axes, mesh, rules)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-# ---------------------------------------------------------------------------
-# jax version compat
-# ---------------------------------------------------------------------------
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across jax versions (0.4.x takes ((name, size), ...))."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Ambient-mesh context: jax.set_mesh on 0.5+, the Mesh context on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh

@@ -21,7 +21,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import TOKENIZER
-from repro.engine.runner import ModelRunner
+from repro.engine.runner import ModelRunner, _bucket
 from repro.engine.sampler import Sampler
 from repro.engine.scheduler import ContinuousBatchScheduler, Request
 from repro.models import registry
@@ -32,6 +32,7 @@ class EngineStats:
     lm_calls: int = 0
     generated_tokens: int = 0
     prompt_tokens: int = 0
+    failed_requests: int = 0       # generate requests out of retries
 
     def add(self, calls: int, prompt: int, gen: int) -> None:
         self.lm_calls += calls
@@ -62,24 +63,33 @@ class InferenceEngine:
         done = sched.run_to_completion()
         self.stats.add(len(prompts), sum(len(r.tokens) for r in done),
                        sum(len(r.out_tokens) for r in done))
-        by_id = {r.rid: r for r in done}
+        by_id = {r.rid: r for r in done if not r.failed}
+        missing = len(prompts) - len(by_id)
+        if missing:  # never answer a failed request with an empty string
+            self.stats.failed_requests += missing
+            raise RuntimeError(f"{missing} of {len(prompts)} generate requests "
+                               "failed after retries")
         return [TOKENIZER.decode([t for t in by_id[i].out_tokens if t != TOKENIZER.eos_id])
-                if i in by_id and not by_id[i].failed else ""
                 for i in range(len(prompts))]
 
     # ------------------------------------------------------------------
     def _last_logits(self, prompts: list[str]) -> np.ndarray:
-        """One forward pass; per-row logits at the last real token. [B, V]."""
+        """One forward pass; per-row log-probs after the last real token. [B, V].
+
+        Rows and widths are padded to power-of-two buckets (at least 8 rows,
+        16 tokens), so the scoring step compiles once per (rows, width)
+        bucket, not once per batch."""
         seqs = [TOKENIZER.encode(p)[: self.runner.max_seq] for p in prompts]
         out = []
         bs = 32
         for i in range(0, len(seqs), bs):
             chunk = seqs[i:i + bs]
-            width = max(16, max(len(s) for s in chunk))
-            toks = TOKENIZER.pad_batch(chunk, width)
-            lp = self.runner.logprobs(toks)  # [b, T, V] log-softmax
-            idx = np.asarray([min(len(s), width) - 1 for s in chunk])
-            out.append(lp[np.arange(len(chunk)), idx])
+            width = min(_bucket(max(len(s) for s in chunk)), self.runner.max_seq)
+            rows = min(_bucket(len(chunk), 8), bs)
+            toks = TOKENIZER.pad_batch(chunk + [[]] * (rows - len(chunk)), width)
+            last = np.asarray([max(len(s), 1) - 1 for s in chunk]
+                              + [0] * (rows - len(chunk)), np.int32)
+            out.append(self.runner.logprobs(toks, last)[: len(chunk)])
             self.stats.add(len(chunk), sum(len(s) for s in chunk), len(chunk))
         return np.concatenate(out, axis=0)
 

@@ -78,13 +78,19 @@ class ModelRunner:
     # -- whole-sequence scoring (no cache) -------------------------------
     @functools.cached_property
     def _score(self):
-        @functools.partial(jax.jit, static_argnames=())
-        def f(params, tokens, extra):
-            logits, _ = registry.forward(self.cfg, params, tokens, extra=extra or None)
-            return jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        @jax.jit
+        def f(params, tokens, last, extra):
+            logits, _ = registry.forward(self.cfg, params, tokens, extra=extra or None,
+                                         last=last)
+            return jax.nn.log_softmax(logits[:, 0].astype(jnp.float32), axis=-1)
 
         return f
 
-    def logprobs(self, tokens: np.ndarray, extra: dict | None = None) -> np.ndarray:
-        """tokens: [B,T] -> log-probs [B,T,V] (teacher-forced)."""
-        return np.asarray(self._score(self.params, jnp.asarray(tokens), extra))
+    def logprobs(self, tokens: np.ndarray, last: np.ndarray,
+                 extra: dict | None = None) -> np.ndarray:
+        """tokens: [B,T], last: [B] position of each row's last real token
+        -> log-probs [B,V] of the token following it.  Causal attention
+        makes right padding invisible to those positions, so callers pad T
+        to a bucket without changing the result."""
+        return np.asarray(self._score(self.params, jnp.asarray(tokens),
+                                      jnp.asarray(last, jnp.int32), extra))

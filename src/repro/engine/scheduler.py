@@ -9,8 +9,10 @@ Fault tolerance / straggler mitigation:
   * per-request wall-clock deadline -> the request is cancelled and
     re-queued (fresh slot, bounded retries) — the cluster-level analogue of
     re-dispatching work from a straggling / failed worker,
-  * a ``fault_hook`` is invoked around model steps so tests can inject
-    worker failures (exceptions) and verify the scheduler recovers.
+  * a ``fault_hook`` is invoked before model steps so tests can inject
+    worker failures (``RuntimeError``) and verify the scheduler recovers.
+    Only the hook's faults are retried: an error from the runner itself (a
+    device or compile error) propagates to the caller.
 """
 from __future__ import annotations
 
@@ -113,10 +115,10 @@ class ContinuousBatchScheduler:
             req.started_at = time.monotonic()
             try:
                 self.fault_hook()
-                logits = self.runner.prefill_into_slot(req.tokens, slot, extra=req.extra)
             except RuntimeError:
                 self._requeue_or_fail(req)
                 return True
+            logits = self.runner.prefill_into_slot(req.tokens, slot, extra=req.extra)
             self.prefill_steps += 1
             req.first_logits = logits
             tok = int(self.sampler(logits[None])[0])
@@ -134,12 +136,12 @@ class ContinuousBatchScheduler:
 
         try:
             self.fault_hook()
-            logits = self.runner.decode(self.slot_next, self.slot_len)
         except RuntimeError:
             # worker fault mid-decode: re-queue everything in flight
             for i in list(active):
                 self._finish(i, failed=True)
             return True
+        logits = self.runner.decode(self.slot_next, self.slot_len)
         self.decode_steps += 1
         toks = self.sampler(logits)
         for i in active:

@@ -43,7 +43,7 @@ def _scan_kernel(p_ref, q_ref, v_ref, m_ref, o_ref, *, normalize: bool):
     v = v_ref[0].astype(jnp.float32)                        # [L, d]
     s = jax.lax.dot_general(q, v, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [bq, L]
-    o_ref[...] = jnp.where(m_ref[0][None, :] > 0, s, MASKED_SCORE)
+    o_ref[...] = jnp.where(m_ref[0] > 0, s, MASKED_SCORE)  # m_ref[0]: [1, L]
 
 
 def cluster_scan(queries, store, mask, probe_blocks, *, block_q: int = 8,
@@ -55,6 +55,9 @@ def cluster_scan(queries, store, mask, probe_blocks, *, block_q: int = 8,
     _, L, _ = store.shape
     nb, slots = probe_blocks.shape
     assert nq == nb * block_q, "queries must be pre-padded to full blocks"
+    # the mask rides as [kc, 1, L]: a (1, 1, L) block spans the array's last
+    # two dims, the layout the TPU lowering accepts for a one-row block
+    mask = jnp.asarray(mask).reshape(-1, 1, L)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -62,7 +65,7 @@ def cluster_scan(queries, store, mask, probe_blocks, *, block_q: int = 8,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j, p: (i, 0)),
             pl.BlockSpec((1, L, d), lambda i, j, p: (p[i, j], 0, 0)),
-            pl.BlockSpec((1, L), lambda i, j, p: (p[i, j], 0)),
+            pl.BlockSpec((1, 1, L), lambda i, j, p: (p[i, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_q, L), lambda i, j, p: (i, j)),
     )
@@ -72,7 +75,7 @@ def cluster_scan(queries, store, mask, probe_blocks, *, block_q: int = 8,
         out_shape=jax.ShapeDtypeStruct((nq, slots * L), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(probe_blocks, jnp.int32), jnp.asarray(queries),
-      jnp.asarray(store), jnp.asarray(mask))
+      jnp.asarray(store), mask)
 
 
 def ivf_search(queries, centroids, store, mask, *, nprobe: int,
@@ -115,7 +118,7 @@ def sharded_ivf_search(queries, centroids, store, mask, *, nprobe: int,
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.ref import ivf_scan_ref
-    from repro.kernels.similarity import shard_mesh, shard_map
+    from repro.kernels.similarity import place_shards, shard_map, shard_mesh
 
     q, nb = pad_queries(jnp.asarray(queries, jnp.float32), block_q)
     q = _unitize(q)
@@ -131,6 +134,7 @@ def sharded_ivf_search(queries, centroids, store, mask, *, nprobe: int,
         # ids are < kc) and their mask is zero anyway
         st = jnp.concatenate([st, jnp.zeros((pad, L, d), st.dtype)])
         mk = jnp.concatenate([mk, jnp.zeros((pad, L), mk.dtype)])
+    st, mk = place_shards(st, mesh), place_shards(mk, mesh)
 
     def body(q, p, st_local, mk_local):
         offset = jax.lax.axis_index("shard") * st_local.shape[0]
@@ -151,5 +155,5 @@ def sharded_ivf_search(queries, centroids, store, mask, *, nprobe: int,
         body, mesh=mesh,
         in_specs=(P(), P(), P("shard", None, None), P("shard", None)),
         out_specs=P(),
-        check_rep=False)(q, probe_blocks, st, mk)
+        check_vma=False)(q, probe_blocks, st, mk)
     return scores[: len(queries)], probe_blocks

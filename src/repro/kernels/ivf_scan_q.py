@@ -39,8 +39,8 @@ def _scan_kernel_q(p_ref, q_ref, v_ref, s_ref, m_ref, o_ref, *,
     v = v_ref[0].astype(jnp.float32)                        # [L, d] int8 -> f32
     s = jax.lax.dot_general(q, v, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [bq, L]
-    s = s * s_ref[0][None, :]           # fused dequantize: per-vector scale
-    o_ref[...] = jnp.where(m_ref[0][None, :] > 0, s, MASKED_SCORE)
+    s = s * s_ref[0]                    # fused dequantize: per-vector scale
+    o_ref[...] = jnp.where(m_ref[0] > 0, s, MASKED_SCORE)  # rows: [1, L]
 
 
 def cluster_scan_q(queries, store_q, scales, mask, probe_blocks, *,
@@ -53,6 +53,10 @@ def cluster_scan_q(queries, store_q, scales, mask, probe_blocks, *,
     _, L, _ = store_q.shape
     nb, slots = probe_blocks.shape
     assert nq == nb * block_q, "queries must be pre-padded to full blocks"
+    # scale and mask rows ride as [kc, 1, L]: a (1, 1, L) block spans the
+    # array's last two dims, the layout the TPU lowering accepts
+    scales = jnp.asarray(scales, jnp.float32).reshape(-1, 1, L)
+    mask = jnp.asarray(mask).reshape(-1, 1, L)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -60,8 +64,8 @@ def cluster_scan_q(queries, store_q, scales, mask, probe_blocks, *,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j, p: (i, 0)),
             pl.BlockSpec((1, L, d), lambda i, j, p: (p[i, j], 0, 0)),
-            pl.BlockSpec((1, L), lambda i, j, p: (p[i, j], 0)),
-            pl.BlockSpec((1, L), lambda i, j, p: (p[i, j], 0)),
+            pl.BlockSpec((1, 1, L), lambda i, j, p: (p[i, j], 0, 0)),
+            pl.BlockSpec((1, 1, L), lambda i, j, p: (p[i, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_q, L), lambda i, j, p: (i, j)),
     )
@@ -71,8 +75,7 @@ def cluster_scan_q(queries, store_q, scales, mask, probe_blocks, *,
         out_shape=jax.ShapeDtypeStruct((nq, slots * L), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(probe_blocks, jnp.int32), jnp.asarray(queries),
-      jnp.asarray(store_q, jnp.int8), jnp.asarray(scales, jnp.float32),
-      jnp.asarray(mask))
+      jnp.asarray(store_q, jnp.int8), scales, mask)
 
 
 def ivf_search_q(queries, centroids, store_q, scales, mask, *, nprobe: int,
@@ -113,7 +116,7 @@ def sharded_ivf_search_q(queries, centroids, store_q, scales, mask, *,
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.ref import ivf_scan_q_ref
-    from repro.kernels.similarity import shard_mesh, shard_map
+    from repro.kernels.similarity import place_shards, shard_map, shard_mesh
 
     q, nb = pad_queries(jnp.asarray(queries, jnp.float32), block_q)
     q = _unitize(q)
@@ -131,6 +134,7 @@ def sharded_ivf_search_q(queries, centroids, store_q, scales, mask, *,
         st = jnp.concatenate([st, jnp.zeros((pad, L, d), st.dtype)])
         sc = jnp.concatenate([sc, jnp.ones((pad, L), sc.dtype)])
         mk = jnp.concatenate([mk, jnp.zeros((pad, L), mk.dtype)])
+    st, sc, mk = (place_shards(a, mesh) for a in (st, sc, mk))
 
     def body(q, p, st_local, sc_local, mk_local):
         offset = jax.lax.axis_index("shard") * st_local.shape[0]
@@ -153,5 +157,5 @@ def sharded_ivf_search_q(queries, centroids, store_q, scales, mask, *,
         in_specs=(P(), P(), P("shard", None, None), P("shard", None),
                   P("shard", None)),
         out_specs=P(),
-        check_rep=False)(q, probe_blocks, st, sc, mk)
+        check_vma=False)(q, probe_blocks, st, sc, mk)
     return scores[: len(queries)], probe_blocks

@@ -4,6 +4,9 @@
     impl="pallas"    force compiled Pallas (TPU)
     impl="interpret" Pallas kernel body interpreted on CPU (tests)
     impl="ref"       pure-jnp oracle
+
+On a TPU host nothing here runs the reference unless ``impl="ref"`` asks
+for it: a device query that fails raises instead of picking a fallback.
 """
 from __future__ import annotations
 
@@ -52,10 +55,7 @@ def _ready(out, sp):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _resolve(impl: str | None) -> str:
@@ -203,34 +203,37 @@ def ivf_delta_search_q(queries, centroids, store_q, scales, mask, delta_q,
 
 
 def _n_devices() -> int:
-    try:
-        return len(jax.devices())
-    except Exception:  # pragma: no cover
-        return 1
+    return len(jax.devices())
 
 
 def _resolve_sharded(impl: str | None, n_shards: int) -> tuple[str, int]:
-    """Sharded ops dispatch: ``shard_map`` needs real devices, so "auto"
-    takes the shard_map path only when the process actually has more than
-    one (clamping the shard count to the device count); otherwise the jnp
-    reference *simulates* the shard partitioning with identical numerics —
-    which is what keeps single-device CI meaningful."""
+    """Sharded ops dispatch -> (mode, shards that run).
+
+    * ``"shard_map"``: more than one device and more than one shard — the
+      shards run on real devices (count clamped to the device count), with
+      Pallas bodies on TPU;
+    * ``"ref"`` (explicit, or "auto" off-TPU on one device): the jnp
+      reference *simulates* the requested partitioning with identical
+      numerics, which keeps single-device CPU tests meaningful;
+    * ``"pallas"`` / ``"interpret"`` (explicit, or "auto" on a one-chip TPU
+      host): the single-device kernel path — the shard contract makes its
+      results identical to the sharded ones."""
     impl = impl or DEFAULT_IMPL
-    if impl == "auto":
-        impl = "shard_map" if _n_devices() > 1 else "ref"
-    if impl in ("pallas", "interpret"):
-        impl = "shard_map"
-    if impl == "shard_map":
-        n_shards = max(1, min(n_shards, _n_devices()))
-    return impl, n_shards
+    if impl == "ref":
+        return "ref", n_shards
+    n_dev = _n_devices()
+    if n_dev > 1 and n_shards > 1:
+        return "shard_map", min(n_shards, n_dev)
+    mode = _resolve("auto") if impl in ("auto", "shard_map") else impl
+    return mode, (n_shards if mode == "ref" else 1)
 
 
 def effective_shards(shards: int) -> int:
     """The shard count the auto dispatch will actually run: clamped to the
     device count on the shard_map path, the requested count on the jnp
-    simulation path.  Index layers use this so per-shard accounting
-    (``scored_vectors_per_shard``) describes the real work split, not the
-    requested layout."""
+    simulation path, 1 on the single-device kernel path.  Index layers use
+    this so per-shard accounting (``scored_vectors_per_shard``) describes
+    the real work split, not the requested layout."""
     _, n = _resolve_sharded(None, shards)
     return n
 
@@ -246,15 +249,20 @@ def sharded_search(queries, corpus, k: int, *, shards: int,
     mode, shards = _resolve_sharded(impl, shards)
     with _kernel_span("sharded_search", mode, nq=len(queries),
                       nc=len(corpus), shards=shards) as sp:
-        if mode == "ref" or shards <= 1:
+        if mode == "ref":
             s, i = ref.sharded_search_ref(jnp.asarray(queries),
                                           jnp.asarray(corpus), k,
                                           max(shards, 1), normalize=normalize)
             s = _ready(s, sp)
             return np.asarray(s), np.asarray(i, np.int64)
-        vals, idx = _sim.sharded_similarity_topk(
-            queries, corpus, k, n_shards=shards, normalize=normalize,
-            use_pallas=_on_tpu())
+        if mode == "shard_map":
+            vals, idx = _sim.sharded_similarity_topk(
+                queries, corpus, k, n_shards=shards, normalize=normalize,
+                use_pallas=_on_tpu())
+        else:  # one device: the similarity kernel over the whole corpus
+            sims = similarity(queries, corpus, normalize=normalize, impl=mode)
+            vals, idx = jax.lax.top_k(jnp.asarray(sims),
+                                      min(k, sims.shape[1]))
         s, i = ref.shard_topk_merge(vals, idx, k)
         s = _ready(s, sp)
         return np.asarray(s), np.asarray(i, np.int64)
@@ -272,15 +280,18 @@ def sharded_ivf_search(queries, centroids, store, mask, *, nprobe: int,
     mode, shards = _resolve_sharded(impl, shards)
     with _kernel_span("sharded_ivf_search", mode, nq=len(queries),
                       nprobe=nprobe, shards=shards) as sp:
-        if mode == "ref" or shards <= 1:
+        if mode == "ref":
             s, p = ref.sharded_ivf_search_ref(
                 jnp.asarray(queries), jnp.asarray(centroids),
                 jnp.asarray(store), jnp.asarray(mask), nprobe=nprobe,
                 n_shards=max(shards, 1), block_q=block_q)
-        else:
+        elif mode == "shard_map":
             s, p = _ivf.sharded_ivf_search(
                 queries, centroids, store, mask, nprobe=nprobe,
                 n_shards=shards, block_q=block_q, use_pallas=_on_tpu())
+        else:
+            s, p = ivf_search(queries, centroids, store, mask, nprobe=nprobe,
+                              block_q=block_q, impl=mode)
         s = _ready(s, sp)
         return np.asarray(s), np.asarray(p)
 
@@ -298,16 +309,19 @@ def sharded_ivf_search_q(queries, centroids, store_q, scales, mask, *,
     mode, shards = _resolve_sharded(impl, shards)
     with _kernel_span("sharded_ivf_search_q", mode, nq=len(queries),
                       nprobe=nprobe, shards=shards) as sp:
-        if mode == "ref" or shards <= 1:
+        if mode == "ref":
             s, p = ref.sharded_ivf_search_q_ref(
                 jnp.asarray(queries), jnp.asarray(centroids),
                 jnp.asarray(store_q, jnp.int8), jnp.asarray(scales),
                 jnp.asarray(mask), nprobe=nprobe, n_shards=max(shards, 1),
                 block_q=block_q)
-        else:
+        elif mode == "shard_map":
             s, p = _ivfq.sharded_ivf_search_q(
                 queries, centroids, store_q, scales, mask, nprobe=nprobe,
                 n_shards=shards, block_q=block_q, use_pallas=_on_tpu())
+        else:
+            s, p = ivf_search_q(queries, centroids, store_q, scales, mask,
+                                nprobe=nprobe, block_q=block_q, impl=mode)
         s = _ready(s, sp)
         return np.asarray(s), np.asarray(p)
 

@@ -21,12 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.kernels.ref import MASKED_SCORE, _unitize, pad_corpus_shards
 
@@ -74,6 +71,13 @@ def shard_mesh(n_shards: int, *, devices=None) -> Mesh:
     return Mesh(np.asarray(devices), ("shard",))
 
 
+def place_shards(x, mesh: Mesh):
+    """Place ``x`` on ``mesh`` split along its leading axis: device ``i`` of
+    the ``shard`` axis holds the ``i``-th equal block of rows."""
+    spec = P("shard", *([None] * (x.ndim - 1)))
+    return jax.device_put(x, NamedSharding(mesh, spec))
+
+
 def sharded_similarity_topk(queries, corpus, k: int, *, n_shards: int,
                             mesh: Mesh | None = None, normalize: bool = True,
                             interpret: bool = False, use_pallas: bool = False):
@@ -93,6 +97,7 @@ def sharded_similarity_topk(queries, corpus, k: int, *, n_shards: int,
         q = _unitize(q)  # the reference's normalization, by definition
         c = _unitize(c)
     c, valid, local = pad_corpus_shards(c, n_shards)
+    c, valid = place_shards(c, mesh), place_shards(valid, mesh)
     k_l = min(k, local)
 
     def body(q, c_local, v_local):
@@ -110,4 +115,4 @@ def sharded_similarity_topk(queries, corpus, k: int, *, n_shards: int,
         body, mesh=mesh,
         in_specs=(P(), P("shard", None), P("shard")),
         out_specs=(P(None, "shard"), P(None, "shard")),
-        check_rep=False)(q, c, valid)
+        check_vma=False)(q, c, valid)

@@ -96,7 +96,7 @@ def build_cell(arch: str, shape: str, mesh, *, rules: str | None = None,
             shd.spec_shardings(ospecs, mesh, rules),
             _input_shardings(in_specs, mesh, rules),
         )
-        with shd.set_mesh(mesh), shd.activation_rules(mesh, rules):
+        with jax.set_mesh(mesh), shd.activation_rules(mesh, rules):
             jitted = jax.jit(step, in_shardings=shardings,
                              out_shardings=(shardings[0], shardings[1], None),
                              donate_argnums=(0, 1))
@@ -120,7 +120,7 @@ def build_cell(arch: str, shape: str, mesh, *, rules: str | None = None,
         extra_sh = {k: ish[k] for k in extra_keys} or None
         shardings = (shd.spec_shardings(pspecs, mesh, rules), ish["tokens"],
                      shd.spec_shardings(cspecs, mesh, rules), extra_sh)
-        with shd.set_mesh(mesh), shd.activation_rules(mesh, rules):
+        with jax.set_mesh(mesh), shd.activation_rules(mesh, rules):
             jitted = jax.jit(serve_prefill, in_shardings=shardings,
                              out_shardings=(None, shardings[2]), donate_argnums=(2,))
             lowered = jitted.lower(params, in_specs["tokens"], cache, extra)
@@ -140,7 +140,7 @@ def build_cell(arch: str, shape: str, mesh, *, rules: str | None = None,
         ish = _input_shardings(in_specs, mesh, rules)
         shardings = (shd.spec_shardings(pspecs, mesh, rules), ish["tokens"],
                      shd.spec_shardings(cspecs, mesh, rules), ish["cache_len"])
-        with shd.set_mesh(mesh), shd.activation_rules(mesh, rules):
+        with jax.set_mesh(mesh), shd.activation_rules(mesh, rules):
             jitted = jax.jit(serve_step, in_shardings=shardings,
                              out_shardings=(None, shardings[2]), donate_argnums=(2,))
             lowered = jitted.lower(params, in_specs["tokens"], cache,

@@ -9,11 +9,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax>=0.5 wants explicit Auto axis types; 0.4.x has no axis_types kwarg.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

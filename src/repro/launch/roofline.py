@@ -50,14 +50,11 @@ _COLLECTIVE_WIRE_FACTOR = {
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: 0.4.x returns a
-    per-module list of dicts, newer versions one dict."""
+    """``compiled.cost_analysis()`` as a dict ({} where the backend has none)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # pragma: no cover - backend without cost analysis
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return ca or {}
 
 

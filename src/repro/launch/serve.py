@@ -11,11 +11,14 @@ scheduling, and gateway metrics.
 
     # persist the semantic cache: the second run answers from disk
     PYTHONPATH=src python -m repro.launch.serve --persist /tmp/semcache.jsonl
+
+Exits 1 when any session ends in a state other than done.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 
 
@@ -47,7 +50,7 @@ def _engine_session(n_records: int, max_seq: int):
     return sess, left, right, SemFrame
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--backend", choices=("sim", "engine"), default="sim")
     ap.add_argument("--sessions", type=int, default=8)
@@ -73,9 +76,13 @@ def main() -> None:
                          "gateway/audit metrics to PATH before shutdown")
     ap.add_argument("--max-seq", type=int, default=256, help="engine backend")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import AdmissionError, Gateway
+    from repro.serve.session import DONE
+
+    enable_compile_cache()
 
     t0 = time.time()
     if args.backend == "sim":
@@ -146,7 +153,12 @@ def main() -> None:
             print(f"[serve] metrics exposition written to {args.metrics_dump}")
     finally:
         gw.close()
+    not_done = [h for h in handles if h.status != DONE]
+    for h in not_done:
+        print(f"[serve] session {h.sid} ended {h.status}: {h.error!r}",
+              file=sys.stderr)
+    return 1 if not_done else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

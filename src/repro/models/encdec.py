@@ -99,14 +99,17 @@ def _decode_logits(params, x, cfg):
     return L.unembed(params["embed"], x, tied=True)
 
 
-def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False):
-    """Teacher-forced decoder pass. tokens [B,S]; extra['audio_frames'] [B,T,d]."""
+def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False, last=None):
+    """Teacher-forced decoder pass. tokens [B,S]; extra['audio_frames'] [B,T,d];
+    ``last`` [B] keeps only those positions' logits."""
     enc_out = encode(params, extra["audio_frames"], cfg=cfg, remat=remat)
     s = tokens.shape[1]
     x = L.embed(params["embed"], tokens).astype(cfg.activation_dtype)
     x = x + params["pos_embed"][:s].astype(x.dtype)
     body = _maybe_remat(functools.partial(_dec_layer_seq, cfg=cfg), cfg, remat)
     x, _ = jax.lax.scan(lambda x, lp: (body(lp, x, enc_out)[0], None), x, params["dec_layers"])
+    if last is not None:
+        x = L.take_positions(x, last)
     return _decode_logits(params, x, cfg), {}
 
 

@@ -134,9 +134,11 @@ def _run_seq(params, x, *, cfg: ModelConfig, remat: bool, collect_state: bool):
     return x, states, kv_caches
 
 
-def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False):
+def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False, last=None):
     x = L.embed(params["embed"], tokens).astype(cfg.activation_dtype)
     x, _, _ = _run_seq(params, x, cfg=cfg, remat=remat, collect_state=False)
+    if last is not None:
+        x = L.take_positions(x, last)
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed({**params.get("out", {}), **params["embed"]}, x, tied=cfg.tie_embeddings)
     return logits, {}

@@ -109,6 +109,12 @@ def embed(params, tokens):
     return jnp.take(params["embedding"], tokens, axis=0)
 
 
+def take_positions(x, pos):
+    """x [B,S,d], pos [B] int -> [B,1,d]: each row's state at ``pos[b]``
+    (the scoring path unembeds only these rows, not all S positions)."""
+    return jnp.take_along_axis(x, pos[:, None, None], axis=1)
+
+
 def unembed(params, x, *, tied: bool):
     w = params["embedding"] if tied else params["head"]
     if tied:

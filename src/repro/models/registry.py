@@ -1,7 +1,7 @@
 """Family dispatch: one uniform interface over all model families.
 
     param_specs(cfg)                          -> SpecTree
-    forward(cfg, params, batch)               -> (logits, aux)
+    forward(cfg, params, tokens, last=None)   -> (logits, aux)
     cache_specs(cfg, batch, max_seq)          -> SpecTree
     prefill(cfg, params, tokens, cache, ...)  -> (logits, cache)
     decode_step(cfg, params, tokens, cache, cache_len, ...) -> (logits, cache)
@@ -39,8 +39,9 @@ def param_structs_tree(cfg: ModelConfig) -> dict:
     return param_structs(param_specs(cfg))
 
 
-def forward(cfg: ModelConfig, params, tokens, *, extra=None, remat=False):
-    return module_for(cfg).forward(params, tokens, cfg=cfg, extra=extra, remat=remat)
+def forward(cfg: ModelConfig, params, tokens, *, extra=None, remat=False, last=None):
+    return module_for(cfg).forward(params, tokens, cfg=cfg, extra=extra, remat=remat,
+                                   last=last)
 
 
 def cache_specs(cfg: ModelConfig, batch: int, max_seq: int) -> SpecTree:

@@ -258,10 +258,13 @@ def _run_layers_seq(params, x, *, cfg: ModelConfig, extra, remat: bool, collect_
     return x, (kv_out if collect_kv else None), aux
 
 
-def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False):
-    """tokens [B,S] -> (logits [B,S,V] f32, aux dict)."""
+def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False, last=None):
+    """tokens [B,S] -> (logits [B,S,V] f32, aux dict); with ``last`` [B]
+    only those positions are unembedded -> logits [B,1,V]."""
     x = L.embed(params["embed"], tokens).astype(cfg.activation_dtype)
     x, _, aux = _run_layers_seq(params, x, cfg=cfg, extra=extra, remat=remat, collect_kv=False)
+    if last is not None:
+        x = L.take_positions(x, last)
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed({**params.get("out", {}), **params["embed"]}, x, tied=cfg.tie_embeddings)
     return logits, aux

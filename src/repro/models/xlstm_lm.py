@@ -103,9 +103,11 @@ def _logits(params, x, cfg):
     return L.unembed({**params.get("out", {}), **params["embed"]}, x, tied=cfg.tie_embeddings)
 
 
-def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False):
+def forward(params, tokens, *, cfg: ModelConfig, extra=None, remat=False, last=None):
     x = L.embed(params["embed"], tokens).astype(cfg.activation_dtype)
     x, _ = _run_seq(params, x, cfg=cfg, remat=remat, collect_state=False)
+    if last is not None:
+        x = L.take_positions(x, last)
     return _logits(params, x, cfg), {}
 
 

@@ -2,6 +2,8 @@
 configs run a real forward + train step on CPU, asserting shapes and no NaNs;
 prefill/decode consistency ties the serving path to the training path.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -45,6 +47,42 @@ def test_forward_and_decode_consistency(arch):
     ld, _ = registry.decode_step(cfg, params, tokens[:, S - 1:S], cache,
                                  jnp.int32(S - 1), extra=extra)
     assert np.allclose(np.asarray(ld[:, 0]), np.asarray(logits[:, S - 1]), atol=1e-3)
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_forward_last_positions_match_full_forward(arch):
+    """``forward(last=pos)`` (the scoring path) unembeds only each row's
+    ``pos`` and equals the full forward's logits there, in every family."""
+    cfg = get_smoke(arch)
+    key = jax.random.PRNGKey(2)
+    params = registry.init_params(cfg, key)
+    B, S = 3, 20
+    tokens = jax.random.randint(key, (B, S), 0, cfg.vocab_size)
+    extra = _extra(cfg, key, B)
+    pos = jnp.asarray([0, 11, S - 1], jnp.int32)
+    full, _ = registry.forward(cfg, params, tokens, extra=extra)
+    got, _ = registry.forward(cfg, params, tokens, extra=extra, last=pos)
+    assert got.shape == (B, 1, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(got[:, 0]),
+                               np.asarray(full[jnp.arange(B), pos]), atol=1e-4)
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_param_draw_matches_eager_ops(arch):
+    """Each leaf is drawn as one jitted program; its values are bit for bit
+    those of the eager ``(scale * normal).astype(dtype)`` it replaces."""
+    specs = registry.param_specs(get_smoke(arch))
+    paths = sorted(specs)
+    for path, key in zip(paths, jax.random.split(jax.random.PRNGKey(4), len(paths))):
+        spec = specs[path]
+        if spec.init not in ("normal", "scaled"):
+            continue
+        fan_in = spec.shape[0] if len(spec.shape) == 1 else int(np.prod(spec.shape[:-1]))
+        scale = (spec.init_scale * 0.02 if spec.init == "normal"
+                 else spec.init_scale / math.sqrt(max(fan_in, 1)))
+        eager = (scale * jax.random.normal(key, spec.shape, jnp.float32)).astype(spec.dtype)
+        np.testing.assert_array_equal(np.asarray(spec.materialize(key), np.float32),
+                                      np.asarray(eager, np.float32), err_msg=str(path))
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)

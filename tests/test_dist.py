@@ -26,8 +26,9 @@ def _run(code: str):
 
 def test_resolve_pspec_divisibility_fallbacks():
     from jax.sharding import PartitionSpec as P
-    from repro.dist.sharding import RULE_TABLES, abstract_mesh, resolve_pspec
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    from repro.dist.sharding import RULE_TABLES, resolve_pspec
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     rules = RULE_TABLES["serve_replicated"]
     # kv_heads=8 divisible by model=4 -> sharded; 6 not -> fallback None
     assert resolve_pspec((512, 8, 128), ("embed_in", "kv_heads", "qkv"), mesh, rules) \
@@ -38,8 +39,9 @@ def test_resolve_pspec_divisibility_fallbacks():
 
 def test_resolve_pspec_axis_used_once():
     from jax.sharding import PartitionSpec as P
-    from repro.dist.sharding import RULE_TABLES, abstract_mesh, resolve_pspec
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    from repro.dist.sharding import RULE_TABLES, resolve_pspec
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     rules = RULE_TABLES["default"]
     # batch takes data; kv_seq then takes model only (data already used)
     spec = resolve_pspec((8, 64, 8, 128), ("batch", "kv_seq", "kv_heads", "qkv"),
@@ -74,8 +76,7 @@ def test_context_parallel_decode_matches_reference():
         x = jax.random.normal(jax.random.PRNGKey(3), (B, 1, cfg.d_model))
         lens = jnp.asarray([3, 33, 63, 0], jnp.int32)
         ref, krf, vrf = A.decode_self_attention(params, x, kc, vc, lens, cfg=cfg)
-        from repro.dist.sharding import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             kcs = jax.device_put(kc, NamedSharding(mesh, P("data", "model", None, None)))
             vcs = jax.device_put(vc, NamedSharding(mesh, P("data", "model", None, None)))
             out, k2, v2 = jax.jit(lambda p, x, k, v, l: CP.cp_decode_self_attention(
@@ -102,8 +103,7 @@ def test_pipeline_parallel_matches_reference():
         tokens = jax.random.randint(jax.random.PRNGKey(1), (16, 32), 0, 200)
         labels = jax.random.randint(jax.random.PRNGKey(2), (16, 32), 0, 200)
         ref, _ = registry.forward(cfg, params, tokens)
-        from repro.dist.sharding import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda p, t: pp_forward(cfg, mesh, p, t, n_micro=4))(params, tokens)
             assert jnp.allclose(got, ref, atol=1e-4)
             loss = make_pp_loss(cfg, mesh, n_micro=4)
@@ -165,7 +165,7 @@ def test_gspmd_train_step_with_rules():
         p_ref, _, m_ref = jax.jit(step)(params, state, batch)
         pspecs = registry.param_specs(cfg)
         ospecs = opt.state_specs(pspecs, ocfg)
-        with shd.set_mesh(mesh), shd.activation_rules(mesh, "default"):
+        with jax.set_mesh(mesh), shd.activation_rules(mesh, "default"):
             sh = (shd.spec_shardings(pspecs, mesh), shd.spec_shardings(ospecs, mesh), None)
             p2, s2, m2 = jax.jit(step, in_shardings=sh, out_shardings=(sh[0], sh[1], None))(
                 params, state, batch)

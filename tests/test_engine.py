@@ -117,3 +117,55 @@ def test_sampler_modes():
     s = Sampler(temperature=1.0, top_k=2, seed=0)
     draws = {int(s(logits)[0]) for _ in range(20)}
     assert draws <= {1, 2}  # top-2 only
+
+
+def test_scoring_path_matches_full_forward_at_last_token(small_engine):
+    """The scoring step gathers each row's last real position on device and
+    pads rows/widths to buckets; its log-probs equal the full teacher-forced
+    forward's at that position, for prompts of mixed lengths."""
+    eng = small_engine
+    prompts = [("claim " * (i + 1)) + "is true?" for i in range(11)]
+    got = eng._last_logits(prompts)
+    assert got.shape == (len(prompts), eng.cfg.vocab_size)
+    for i, p in enumerate(prompts):
+        toks = jnp.asarray([TOKENIZER.encode(p)], jnp.int32)
+        logits, _ = registry.forward(eng.cfg, eng.runner.params, toks)
+        want = jax.nn.log_softmax(logits[0, -1].astype(jnp.float32))
+        np.testing.assert_allclose(got[i], np.asarray(want), atol=1e-4)
+    # batches of 3, 5 and 8 prompts share the 8-row bucket, 9 takes the
+    # 16-row one: two compiles at most, whatever the batch sizes
+    n0 = eng.runner._score._cache_size()
+    same_len = [f"claim {i:03d} is true?" for i in range(9)]
+    for n in (3, 5, 8, 9):
+        eng._last_logits(same_len[:n])
+    assert eng.runner._score._cache_size() - n0 <= 2
+
+
+class _BrokenRunner:
+    """A runner whose device step fails (as an XLA runtime error would)."""
+    max_slots, max_seq = 2, 64
+
+    def prefill_into_slot(self, tokens, slot, extra=None):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    def decode(self, tokens, lens):  # pragma: no cover - never reached
+        raise AssertionError
+
+
+def test_generate_propagates_runner_errors(small_engine):
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng.runner, eng.sampler = _BrokenRunner(), Sampler()
+    eng.stats = type(small_engine.stats)()
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        eng.generate(["a prompt"], max_new_tokens=2)
+
+
+def test_generate_raises_when_requests_run_out_of_retries(small_engine):
+    def always_fail():
+        raise RuntimeError("injected worker fault")
+
+    before = small_engine.stats.failed_requests
+    with pytest.raises(RuntimeError, match="2 of 2 generate requests failed"):
+        small_engine.generate(["p0", "p1"], max_new_tokens=2,
+                              fault_hook=always_fail)
+    assert small_engine.stats.failed_requests == before + 2

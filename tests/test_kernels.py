@@ -75,3 +75,16 @@ def test_ops_ref_dispatch_on_cpu():
     q = np.eye(4, dtype=np.float32)
     s = ops.similarity(q, q, impl="auto")
     np.testing.assert_allclose(np.diag(s), np.ones(4), atol=1e-6)
+
+
+def test_ops_device_query_errors_propagate(monkeypatch):
+    """A failing device query raises: "auto" never silently picks the jnp
+    reference because the platform could not be read."""
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+
+    monkeypatch.setattr(ops.jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        ops._resolve("auto")
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        ops.effective_shards(4)

@@ -253,6 +253,34 @@ def test_sharded_ivf_scores_identical_to_unsharded(rng):
     np.testing.assert_allclose(s_u, s_s, rtol=1e-6)
 
 
+def test_sharded_ops_one_device_kernel_path(rng):
+    """On a one-device process an explicit kernel impl runs the single-device
+    kernel (interpreted here; Pallas on a one-chip TPU host), not the jnp
+    shard simulation; "auto" off-TPU keeps the simulation.  Both agree with
+    the reference."""
+    from repro.index.ivf_index import IVFIndex
+    assert ops._resolve_sharded("interpret", 4) == ("interpret", 1)
+    assert ops._resolve_sharded(None, 4) == ("ref", 4)
+    corpus = rng.normal(size=(600, 16)).astype(np.float32)
+    q = rng.normal(size=(5, 16)).astype(np.float32)
+    s_r, i_r = ops.sharded_search(q, corpus, 7, shards=4, impl="ref")
+    s_k, i_k = ops.sharded_search(q, corpus, 7, shards=4, impl="interpret")
+    np.testing.assert_array_equal(i_k, i_r)
+    np.testing.assert_allclose(s_k, s_r, atol=1e-5)
+    for quant in ("none", "int8"):
+        ivf = IVFIndex(corpus, n_clusters=12, seed=1, quantize=quant)
+        if quant == "none":
+            args, run = (q, ivf.centroids, ivf.store, ivf.store_mask), \
+                ops.sharded_ivf_search
+        else:
+            args, run = (q, ivf.centroids, ivf.store_q, ivf.store_scales,
+                         ivf.store_mask), ops.sharded_ivf_search_q
+        s1, p1 = run(*args, nprobe=4, shards=4, impl="ref")
+        s2, p2 = run(*args, nprobe=4, shards=4, impl="interpret")
+        np.testing.assert_array_equal(p1, p2)
+        np.testing.assert_allclose(s1, s2, atol=1e-5)
+
+
 def test_sharded_index_degenerate_equals_exact(rng):
     """Acceptance: sharded search at nprobe=n_clusters == ops.similarity
     exact scan, and the sharded exact index == the unsharded one."""

@@ -70,11 +70,10 @@ def test_collective_bytes_multi_device_subprocess():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze_text
-        from repro.dist.sharding import set_mesh
         from repro.launch.mesh import make_test_mesh
         mesh = make_test_mesh((8,), ("d",))
         x = jax.ShapeDtypeStruct((1024, 64), jnp.float32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # contraction over the sharded dim forces an all-reduce
             c = jax.jit(lambda a: (a * a).sum(),
                         in_shardings=NamedSharding(mesh, P("d", None))).lower(x).compile()

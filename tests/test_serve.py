@@ -3,9 +3,11 @@ persistence, gateway admission/fairness/cancellation/deadlines, per-session
 accounting, and the satellite fixes (CountedModel role attribution, scheduler
 retry-state reset).
 """
+import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -450,3 +452,46 @@ def test_scheduler_exhausted_retries_reports_failure_with_clean_state():
     done = sched.run_to_completion()
     assert len(done) == 1 and done[0].failed and not done[0].done
     assert done[0].out_tokens == [] and done[0].started_at is None
+
+
+# ---------------------------------------------------------------------------
+# serving CLI exit status + compile cache location
+# ---------------------------------------------------------------------------
+
+
+def test_serve_cli_exit_status(monkeypatch, capsys):
+    """The CLI returns 0 when every session is done and 1 when one fails."""
+    import repro.launch.compile_cache as cc
+    from repro.launch import serve as cli
+    monkeypatch.setattr(cc, "enable_compile_cache", lambda: None)
+    argv = ["--sessions", "2", "--tenants", "1", "--records", "30"]
+    assert cli.main(argv) == 0
+    real = cli._sim_session
+
+    class _LostDevice:
+        def __getattr__(self, name):
+            raise RuntimeError("device lost")
+
+    def broken(n, seed):           # every oracle call fails mid-session
+        sess, left, right, frame = real(n, seed)
+        sess.oracle._m = _LostDevice()
+        return sess, left, right, frame
+
+    monkeypatch.setattr(cli, "_sim_session", broken)
+    assert cli.main(argv) == 1
+    assert "ended failed" in capsys.readouterr().err
+
+
+def test_compile_cache_dir_follows_env(monkeypatch):
+    from repro.launch import compile_cache as cc
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax-cache")
+    assert cc.compile_cache_dir() == "/elsewhere/jax-cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(os.path.dirname(__file__), "..", ".jax_cache")
+    assert cc.compile_cache_dir() == os.path.realpath(want)
+    prev = jax.config.jax_compilation_cache_dir
+    try:   # nothing compiles in between, so no cache entry is written
+        assert cc.enable_compile_cache() == os.path.realpath(want)
+        assert jax.config.jax_compilation_cache_dir == os.path.realpath(want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
