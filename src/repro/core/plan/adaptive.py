@@ -214,7 +214,7 @@ class AdaptivePlanExecutor(PartitionedExecutor):
         return rows
 
     def _apply_filter(self, f: N.Filter, part, rows, *, reason=None):
-        if _trace.current_tracer() is None:
+        if not _trace.active():
             if reason:
                 self._replan("reorder_filters", f, reason)
             return self._filter_body(f, part, rows)

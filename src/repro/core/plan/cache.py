@@ -94,7 +94,7 @@ class BatchedModelCache:
         one batch may be larger than the cache capacity, in which case
         inserting the tail of the batch evicts its own head."""
         sp = _trace.NOOP_SPAN
-        if _trace.current_tracer() is not None:
+        if _trace.active():
             # one lookup span per batched cache consult (not per prompt)
             role = self._ns[0] if self._ns else "private"
             sp_cm = _trace.span(f"cache/{role}.{kind}", kind="cache_lookup",

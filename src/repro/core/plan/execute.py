@@ -228,7 +228,7 @@ class PlanExecutor:
         if self.matviews is not None:
             inner = fn
             fn = lambda n: self._matview_dispatch(n, inner)
-        if _trace.current_tracer() is None:
+        if not _trace.active():
             return fn(node)
         # one span per plan node; node_id keys the explain_analyze join
         # between the executed span tree and the optimized plan tree

@@ -25,6 +25,7 @@ from repro.engine.runner import ModelRunner, _bucket
 from repro.engine.sampler import Sampler
 from repro.engine.scheduler import ContinuousBatchScheduler, Request
 from repro.models import registry
+from repro.obs import trace as _trace
 
 
 @dataclasses.dataclass
@@ -53,24 +54,26 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     def generate(self, prompts: list[str], *, max_new_tokens: int = 48,
                  fault_hook=None) -> list[str]:
-        sched = ContinuousBatchScheduler(self.runner, sampler=self.sampler,
-                                         fault_hook=fault_hook)
-        for i, p in enumerate(prompts):
-            toks = np.asarray(TOKENIZER.encode(p)[: self.runner.max_seq - max_new_tokens - 1],
-                              np.int32)
-            sched.submit(Request(rid=i, tokens=toks, max_new_tokens=max_new_tokens,
-                                 stop_id=TOKENIZER.eos_id))
-        done = sched.run_to_completion()
-        self.stats.add(len(prompts), sum(len(r.tokens) for r in done),
-                       sum(len(r.out_tokens) for r in done))
-        by_id = {r.rid: r for r in done if not r.failed}
-        missing = len(prompts) - len(by_id)
-        if missing:  # never answer a failed request with an empty string
-            self.stats.failed_requests += missing
-            raise RuntimeError(f"{missing} of {len(prompts)} generate requests "
-                               "failed after retries")
-        return [TOKENIZER.decode([t for t in by_id[i].out_tokens if t != TOKENIZER.eos_id])
-                for i in range(len(prompts))]
+        with _trace.span("engine/generate", "engine", event="repro.engine.generate",
+                         requests=len(prompts)):
+            sched = ContinuousBatchScheduler(self.runner, sampler=self.sampler,
+                                             fault_hook=fault_hook)
+            for i, p in enumerate(prompts):
+                toks = np.asarray(TOKENIZER.encode(p)[: self.runner.max_seq - max_new_tokens - 1],
+                                  np.int32)
+                sched.submit(Request(rid=i, tokens=toks, max_new_tokens=max_new_tokens,
+                                     stop_id=TOKENIZER.eos_id))
+            done = sched.run_to_completion()
+            self.stats.add(len(prompts), sum(len(r.tokens) for r in done),
+                           sum(len(r.out_tokens) for r in done))
+            by_id = {r.rid: r for r in done if not r.failed}
+            missing = len(prompts) - len(by_id)
+            if missing:  # never answer a failed request with an empty string
+                self.stats.failed_requests += missing
+                raise RuntimeError(f"{missing} of {len(prompts)} generate requests "
+                                   "failed after retries")
+            return [TOKENIZER.decode([t for t in by_id[i].out_tokens if t != TOKENIZER.eos_id])
+                    for i in range(len(prompts))]
 
     # ------------------------------------------------------------------
     def _last_logits(self, prompts: list[str]) -> np.ndarray:
@@ -79,19 +82,29 @@ class InferenceEngine:
         Rows and widths are padded to power-of-two buckets (at least 8 rows,
         16 tokens), so the scoring step compiles once per (rows, width)
         bucket, not once per batch."""
-        seqs = [TOKENIZER.encode(p)[: self.runner.max_seq] for p in prompts]
-        out = []
-        bs = 32
-        for i in range(0, len(seqs), bs):
-            chunk = seqs[i:i + bs]
-            width = min(_bucket(max(len(s) for s in chunk)), self.runner.max_seq)
-            rows = min(_bucket(len(chunk), 8), bs)
-            toks = TOKENIZER.pad_batch(chunk + [[]] * (rows - len(chunk)), width)
-            last = np.asarray([max(len(s), 1) - 1 for s in chunk]
-                              + [0] * (rows - len(chunk)), np.int32)
-            out.append(self.runner.logprobs(toks, last)[: len(chunk)])
-            self.stats.add(len(chunk), sum(len(s) for s in chunk), len(chunk))
-        return np.concatenate(out, axis=0)
+        with _trace.span("engine/score", "engine", event="repro.engine.score",
+                         rows=len(prompts)) as sp:
+            out = []
+            bs = 32
+            tokens = padded = 0
+            for i in range(0, len(prompts), bs):
+                with _trace.span("engine/score.prep", "engine",
+                                 event="repro.engine.score.prep") as prep:
+                    chunk = [TOKENIZER.encode(p)[: self.runner.max_seq]
+                             for p in prompts[i:i + bs]]
+                    width = min(_bucket(max(len(s) for s in chunk)), self.runner.max_seq)
+                    rows = min(_bucket(len(chunk), 8), bs)
+                    toks = TOKENIZER.pad_batch(chunk + [[]] * (rows - len(chunk)), width)
+                    last = np.asarray([max(len(s), 1) - 1 for s in chunk]
+                                      + [0] * (rows - len(chunk)), np.int32)
+                    prep.set(rows=len(chunk), width=width)
+                out.append(self.runner.logprobs(toks, last)[: len(chunk)])
+                n = sum(len(s) for s in chunk)
+                self.stats.add(len(chunk), n, len(chunk))
+                tokens += n
+                padded += rows * width
+            sp.set(tokens=tokens, padded_tokens=padded, chunks=len(out))
+            return np.concatenate(out, axis=0)
 
     def predicate(self, prompts: list[str]) -> tuple[np.ndarray, np.ndarray]:
         """Returns (passes [B] bool, score [B]: p(True | {True,False}))."""

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import registry
+from repro.obs import trace as _trace
 
 
 def _bucket(n: int, minimum: int = 16) -> int:
@@ -66,25 +67,25 @@ class ModelRunner:
         logits, cache1 = self._prefill(self.params, jnp.asarray(padded), cache1,
                                        jnp.int32(t), extra, bucket)
         self.cache = self._write_slot(self.cache, cache1, jnp.int32(slot))
-        return np.asarray(logits)
+        return _fetch(logits)
 
     # -- one decode step over all slots ----------------------------------
     def decode(self, tokens: np.ndarray, lens: np.ndarray):
         """tokens: [slots] int32 (next input per slot); lens: [slots] int32."""
         logits, self.cache = self._decode(self.params, jnp.asarray(tokens[:, None]),
                                           self.cache, jnp.asarray(lens))
-        return np.asarray(logits)
+        return _fetch(logits)
 
     # -- whole-sequence scoring (no cache) -------------------------------
     @functools.cached_property
     def _score(self):
         @jax.jit
-        def f(params, tokens, last, extra):
+        def score(params, tokens, last, extra):
             logits, _ = registry.forward(self.cfg, params, tokens, extra=extra or None,
                                          last=last)
             return jax.nn.log_softmax(logits[:, 0].astype(jnp.float32), axis=-1)
 
-        return f
+        return score
 
     def logprobs(self, tokens: np.ndarray, last: np.ndarray,
                  extra: dict | None = None) -> np.ndarray:
@@ -92,5 +93,12 @@ class ModelRunner:
         -> log-probs [B,V] of the token following it.  Causal attention
         makes right padding invisible to those positions, so callers pad T
         to a bucket without changing the result."""
-        return np.asarray(self._score(self.params, jnp.asarray(tokens),
-                                      jnp.asarray(last, jnp.int32), extra))
+        return _fetch(self._score(self.params, jnp.asarray(tokens),
+                                  jnp.asarray(last, jnp.int32), extra))
+
+
+def _fetch(x) -> np.ndarray:
+    """Bring a step's output to the host: the one place a step waits for
+    the device, under its own span."""
+    with _trace.span("runner/fetch", "runner", event="repro.runner.fetch"):
+        return np.asarray(x)

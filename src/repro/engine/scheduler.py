@@ -23,8 +23,9 @@ from typing import Callable
 
 import numpy as np
 
-from repro.engine.runner import ModelRunner
+from repro.engine.runner import ModelRunner, _bucket
 from repro.engine.sampler import Sampler
+from repro.obs import trace as _trace
 
 
 @dataclasses.dataclass
@@ -118,10 +119,14 @@ class ContinuousBatchScheduler:
             except RuntimeError:
                 self._requeue_or_fail(req)
                 return True
-            logits = self.runner.prefill_into_slot(req.tokens, slot, extra=req.extra)
+            n = len(req.tokens)
+            with _trace.span("sched/prefill", "sched", event="repro.sched.prefill",
+                             tokens=n, bucket=min(_bucket(n), self.runner.max_seq)):
+                logits = self.runner.prefill_into_slot(req.tokens, slot, extra=req.extra)
             self.prefill_steps += 1
             req.first_logits = logits
-            tok = int(self.sampler(logits[None])[0])
+            with _trace.span("sched/sample", "sched", event="repro.sched.sample", rows=1):
+                tok = int(self.sampler(logits[None])[0])
             req.out_tokens.append(tok)
             self.slot_req[slot] = req
             self.slot_len[slot] = len(req.tokens)
@@ -141,9 +146,13 @@ class ContinuousBatchScheduler:
             for i in list(active):
                 self._finish(i, failed=True)
             return True
-        logits = self.runner.decode(self.slot_next, self.slot_len)
+        with _trace.span("sched/decode", "sched", event="repro.sched.decode",
+                         live=len(active), slots=self.runner.max_slots):
+            logits = self.runner.decode(self.slot_next, self.slot_len)
         self.decode_steps += 1
-        toks = self.sampler(logits)
+        with _trace.span("sched/sample", "sched", event="repro.sched.sample",
+                         rows=len(logits)):
+            toks = self.sampler(logits)
         for i in active:
             req = self.slot_req[i]
             self.slot_len[i] += 1

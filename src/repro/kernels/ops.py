@@ -31,25 +31,24 @@ DEFAULT_IMPL = "auto"
 
 @contextlib.contextmanager
 def _kernel_span(name: str, mode: str, **attrs):
-    """Kernel-dispatch observability, active only under a tracer: a
-    ``jax.named_scope`` so the dispatch is labeled in XLA/Perfetto device
-    profiles, plus a ``kind="kernel"`` trace span so host-side kernel time
-    is attributed to the owning operator span.  Yields the span (None when
+    """Kernel-dispatch observability: a ``kind="kernel"`` span, so host-side
+    kernel time is attributed to the owning operator span and shows in a
+    JAX profile as ``repro.kernel.<name>``.  Yields the span (None when
     tracing is off — the zero-overhead default path)."""
-    if _trace.current_tracer() is None:
+    if not _trace.active():
         yield None
         return
-    with jax.named_scope(f"repro.{name}"):
-        with _trace.span(f"kernel/{name}", kind="kernel",
-                         impl=mode, **attrs) as sp:
-            yield sp
+    with _trace.span(f"kernel/{name}", kind="kernel",
+                     event=f"repro.kernel.{name}", impl=mode, **attrs) as sp:
+        yield sp
 
 
 def _ready(out, sp):
     """Under a tracer, block until device work finishes so the enclosing
-    kernel span measures compute, not dispatch; untraced calls keep jax's
-    async dispatch (the ``np.asarray`` conversions sync anyway)."""
-    if sp is not None:
+    kernel span measures compute, not dispatch; calls that only a JAX
+    profile records, and untraced calls, keep jax's async dispatch (the
+    ``np.asarray`` conversions sync anyway)."""
+    if isinstance(sp, _trace.Span):
         out = jax.block_until_ready(out)
     return out
 

@@ -3,11 +3,12 @@
 One ``Tracer`` per traced run (or per gateway); spans nest through a
 thread-local context so every layer — session, plan stage, operator,
 partition fragment, dispatcher batch, kernel dispatch, index build, cache
-lookup — attributes its work to the right parent without passing handles
-through call signatures.  Tracing is off by default: the module-level
-``span()`` returns a shared no-op context manager when no tracer is
-installed on the calling thread, so the off path costs one thread-local
-read per call site.
+lookup, engine scoring and scheduler step — attributes its work to the
+right parent without passing handles through call signatures.  Tracing is
+off by default: the module-level ``span()`` returns a shared no-op context
+manager when no tracer is installed on the calling thread and no JAX
+profile is being taken, so the off path costs a thread-local read and a
+profiler flag read per call site.
 
 Cross-thread propagation mirrors ``core.accounting``: the coordinating
 thread snapshots its context with ``capture()`` and fragment / worker /
@@ -17,16 +18,27 @@ on other threads still parent into the owning session or operator span.
 Exports: ``Tracer.export_jsonl()`` (one span per line) and
 ``Tracer.export_chrome()`` (Chrome ``trace_event`` JSON, loadable in
 Perfetto / ``chrome://tracing``).
+
+Every span has a second sink: while a JAX profile is being taken, the span
+also opens a ``jax.profiler.TraceAnnotation``, so it lands in the profile on
+the same clock as the device's events, with or without a ``Tracer``.  The
+event's name is stable and carries no ids: the span's ``event`` where the
+call site gives one (``repro.dispatch.oracle.predicate``), else
+``repro.<kind>`` with the span's name as the stat ``name``.  Scalar attrs
+become the event's stats, including those set after the span opened.  No
+span waits for the device.
 """
 from __future__ import annotations
 
 import contextlib
 import itertools
 import json
+import sys
 import threading
 import time
 
 _ctx = threading.local()
+_annotation = None        # jax.profiler.TraceAnnotation, once JAX is loaded
 
 
 def current_tracer() -> "Tracer | None":
@@ -37,12 +49,57 @@ def current_span() -> "Span | None":
     return getattr(_ctx, "span", None)
 
 
+def _profiler():
+    """``jax.profiler.TraceAnnotation`` while a JAX profile is being taken,
+    else None.  A process that has not imported JAX takes no profile, so the
+    simulated-backend path never imports it from here."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation if _annotation.is_enabled() else None
+
+
+def active() -> bool:
+    """Whether a span opened on this thread is recorded anywhere: a tracer
+    is installed here or a JAX profile is being taken.  Call sites whose
+    span attrs cost something to build test this first."""
+    return current_tracer() is not None or _profiler() is not None
+
+
+def _stats(attrs: dict) -> dict:
+    """The attrs a profiler event can carry as stats: scalars (bools as
+    ints); anything else stays with the tracer's span only."""
+    out = {}
+    for k, v in attrs.items():
+        if isinstance(v, bool):
+            out[k] = int(v)
+        elif isinstance(v, (int, float, str)):
+            out[k] = v
+    return out
+
+
+def _annotate(name: str, kind: str, event: "str | None", attrs: dict):
+    """An unopened profiler event for a span, or None when no profile is
+    being taken."""
+    ann = _profiler()
+    if ann is None:
+        return None
+    stats = _stats(attrs)
+    if event is None:
+        event = f"repro.{kind}"
+        stats["name"] = name
+    return ann(event, **stats)
+
+
 class Span:
     """One timed unit of work.  ``attrs`` are typed-by-convention: counts
     are ints, seconds/thresholds are floats, identifiers are strings."""
 
     __slots__ = ("span_id", "parent_id", "name", "kind", "t0", "t1",
-                 "attrs", "thread")
+                 "attrs", "thread", "_ann")
 
     def __init__(self, span_id: int, parent_id: int | None, name: str,
                  kind: str, attrs: dict):
@@ -54,6 +111,7 @@ class Span:
         self.t1: float | None = None
         self.attrs = attrs
         self.thread = threading.get_ident()
+        self._ann = None          # the profiler's event, while profiling
 
     @property
     def dur_s(self) -> float:
@@ -62,9 +120,11 @@ class Span:
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_stats(attrs))
 
     def add(self, key: str, n: float = 1) -> None:
-        self.attrs[key] = self.attrs.get(key, 0) + n
+        self.set(**{key: self.attrs.get(key, 0) + n})
 
     def as_dict(self, origin: float = 0.0) -> dict:
         return {
@@ -105,6 +165,38 @@ class _NoopCM:
 NOOP_SPAN = _NoopSpan()
 _NOOP_CM = _NoopCM()
 
+
+class _ProfiledSpan:
+    """A span that only the JAX profiler records (no tracer installed):
+    its context manager and its handle in one.  It installs nothing as the
+    thread's current span."""
+
+    __slots__ = ("_ann", "_attrs")
+
+    def __init__(self, ann, attrs: dict):
+        self._ann = ann
+        self._attrs = attrs
+
+    def __enter__(self) -> "_ProfiledSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> None:
+        self._attrs.update(attrs)
+        self._ann.set_metadata(**_stats(attrs))
+
+    def add(self, key: str, n: float = 1) -> None:
+        self.set(**{key: self._attrs.get(key, 0) + n})
+
+
+def _profiled(name: str, kind: str, event: "str | None", attrs: dict):
+    ann = _annotate(name, kind, event, attrs)
+    return _NOOP_CM if ann is None else _ProfiledSpan(ann, attrs)
+
 # attribute keys whose values are summed when aggregating spans
 _COUNTER_KEYS = ("oracle_calls", "proxy_calls", "embed_calls",
                  "compare_calls", "generate_calls", "cache_hits",
@@ -127,19 +219,25 @@ class Tracer:
 
     # -- span lifecycle ---------------------------------------------------
     @contextlib.contextmanager
-    def span(self, name: str, kind: str = "span", **attrs):
+    def span(self, name: str, kind: str = "span", *, event: "str | None" = None,
+             **attrs):
         """Open a span parented to this thread's current span (if this
         tracer is the one installed here), install it as current, and
-        record it on exit."""
+        record it on exit (and in the JAX profile, while one is taken)."""
         parent = current_span() if current_tracer() is self else None
         sp = Span(next(self._ids),
                   parent.span_id if parent is not None else None,
                   name, kind, attrs)
+        sp._ann = _annotate(name, kind, event, attrs)
+        if sp._ann is not None:
+            sp._ann.__enter__()
         prev = (current_tracer(), current_span())
         _ctx.tracer, _ctx.span = self, sp
         try:
             yield sp
         finally:
+            if sp._ann is not None:
+                sp._ann.__exit__(None, None, None)
             sp.t1 = time.monotonic()
             _ctx.tracer, _ctx.span = prev
             with self._lock:
@@ -234,21 +332,25 @@ class Tracer:
 
 # -- module-level context helpers ----------------------------------------
 
-def span(name: str, kind: str = "span", **attrs):
-    """Open a span on this thread's installed tracer; no-op (and no attrs
-    evaluation cost beyond the call) when tracing is off."""
+def span(name: str, kind: str = "span", *, event: "str | None" = None,
+         **attrs):
+    """Open a span on this thread's installed tracer, or in the JAX profile
+    alone while one is taken; no-op (and no attrs evaluation cost beyond
+    the call) when neither records.  ``event`` names the profiler's event
+    (default ``repro.<kind>``)."""
     t = current_tracer()
     if t is None:
-        return _NOOP_CM
-    return t.span(name, kind, **attrs)
+        return _profiled(name, kind, event, attrs)
+    return t.span(name, kind, event=event, **attrs)
 
 
-def span_in(tracer: "Tracer | None", name: str, kind: str = "span", **attrs):
+def span_in(tracer: "Tracer | None", name: str, kind: str = "span", *,
+            event: "str | None" = None, **attrs):
     """Open a span on an explicit tracer (dispatcher/subscription threads
     that hold a tracer handle rather than inheriting thread context)."""
     if tracer is None:
-        return _NOOP_CM
-    return tracer.span(name, kind, **attrs)
+        return _profiled(name, kind, event, attrs)
+    return tracer.span(name, kind, event=event, **attrs)
 
 
 def capture() -> tuple:

@@ -37,11 +37,13 @@ class DispatchError(RuntimeError):
 
 
 class _ParkedCall:
-    __slots__ = ("prompts", "tag", "event", "rows", "owned", "shared", "error")
+    __slots__ = ("prompts", "tag", "event", "rows", "owned", "shared", "error",
+                 "t_submit")
 
     def __init__(self, prompts: list[str], tag: str | None):
         self.prompts = prompts
         self.tag = tag                     # session id, for cross-query stats
+        self.t_submit = time.monotonic()   # start of its queue wait
         self.event = threading.Event()
         self.rows: list | None = None
         self.owned = 0                     # unique prompts this call paid for
@@ -179,8 +181,14 @@ class MicroBatchDispatcher:
 
     def _execute(self, key: tuple, calls: list[_ParkedCall]) -> None:
         role, kind, extra = key
+        # how long the parked calls waited for this batch to start
+        start = time.monotonic()
+        waits = [start - c.t_submit for c in calls]
         with _trace.span_in(self._tracer, f"dispatch/{role}.{kind}",
-                            "dispatch_batch", role=role, call_kind=kind) as sp:
+                            "dispatch_batch", event=f"repro.dispatch.{role}.{kind}",
+                            role=role, call_kind=kind, fused_calls=len(calls),
+                            wait_ms_sum=1e3 * sum(waits),
+                            wait_ms_max=1e3 * max(waits, default=0.0)) as sp:
             self._execute_batch(key, calls, sp)
 
     def _execute_batch(self, key: tuple, calls: list[_ParkedCall],
@@ -223,8 +231,7 @@ class MicroBatchDispatcher:
                         [(role, kind, *extra, p) for p in todo], answered,
                         owners=[owner_of[p].tag for p in todo])
             # batch fusion width + dedup/store effect, on the batch span
-            sp.set(fused_calls=len(calls), unique_prompts=len(order),
-                   backend_prompts=len(todo),
+            sp.set(unique_prompts=len(order), backend_prompts=len(todo),
                    store_hits=len(order) - len(todo),
                    sessions=len({c.tag for c in calls}))
             prompt_sets = [set(c.prompts) for c in calls]

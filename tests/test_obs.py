@@ -8,7 +8,9 @@ histogram, explain_plan's predicted selectivity, and the shared-OpStats
 concurrency stress test.
 """
 import json
+import sys
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -449,3 +451,162 @@ def test_traced_run_is_record_identical_to_untraced():
         traced = _pipeline(left, right, world).collect()
     assert traced.records == untraced.records
     assert tr.spans(kind="plan_stage")
+
+
+# ---------------------------------------------------------------------------
+# the JAX profiler as a second sink
+# ---------------------------------------------------------------------------
+
+ROOT = Path(__file__).resolve().parents[1]
+
+# every span the benchmark's readers use, with the stats each must carry
+PROGRAM_SPANS = {
+    "repro.dispatch.oracle.predicate": {"fused_calls", "unique_prompts", "backend_prompts",
+                                        "store_hits", "wait_ms_sum", "wait_ms_max"},
+    "repro.dispatch.oracle.generate": {"fused_calls", "wait_ms_sum", "wait_ms_max"},
+    "repro.engine.score": {"rows", "tokens", "padded_tokens", "chunks"},
+    "repro.engine.score.prep": {"rows", "width"},
+    "repro.runner.fetch": set(),
+    "repro.engine.generate": {"requests"},
+    "repro.sched.prefill": {"tokens", "bucket"},
+    "repro.sched.decode": {"live", "slots"},
+    "repro.sched.sample": {"rows"},
+}
+
+
+def _profile_options():
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
+
+
+def _bench():
+    """The benchmark's span helpers (``bench/`` at the repository's root)."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from bench import harness, program_spans, trace_reduce
+    return harness, program_spans, trace_reduce
+
+
+def _profiled_events(tmp_path, monkeypatch, body):
+    """Run ``body`` under a JAX profile, inside a ``bench.window`` event, and
+    read the program's events back as the benchmark's readers do."""
+    import jax
+    harness, program_spans, trace_reduce = _bench()
+    with jax.profiler.trace(str(tmp_path), profiler_options=_profile_options()):
+        with jax.profiler.TraceAnnotation("bench.window"):
+            body()
+    monkeypatch.setattr(harness, "TRACE_DIR", tmp_path)
+    red = trace_reduce.reduce(trace_reduce.find_xplane(str(tmp_path)))
+    ctx = harness.MetricContext(trace=red, totals={}, cell=None, peak={})
+    return program_spans.events(ctx)
+
+
+def test_no_tracer_and_no_profile_is_the_shared_noop():
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()
+    assert T.current_tracer() is None and not T.active()
+    assert T.span("engine/score", "engine", event="repro.engine.score", rows=1) \
+        is T._NOOP_CM
+    assert T.span_in(None, "dispatch/oracle.predicate", "dispatch_batch") is T._NOOP_CM
+
+
+def test_gateway_on_a_real_engine_emits_every_program_span(tmp_path, monkeypatch):
+    from repro.configs import get_smoke
+    from repro.core.backends.jax_engine import EngineModel
+    from repro.data.tokenizer import TOKENIZER
+    from repro.engine.engine import InferenceEngine
+
+    cfg = get_smoke("llama3.2-3b").with_(vocab_size=TOKENIZER.vocab_size, num_layers=1,
+                                         d_model=32, d_ff=64)
+    engine = InferenceEngine(cfg, max_slots=2, max_seq=64)
+    session = Session(oracle=EngineModel(engine, max_new_tokens=3))
+    records = [{"claim": f"claim {i}"} for i in range(3)]
+
+    def queries():
+        with Gateway(session, max_inflight=2) as gw:
+            gw.submit(SemFrame(records, session).lazy()
+                      .sem_filter("the {claim} holds")).result(timeout=120.0)
+            gw.submit(SemFrame(records, session).lazy()
+                      .sem_map("shorten {claim}")).result(timeout=120.0)
+
+    events = _profiled_events(tmp_path, monkeypatch, queries)
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e.name, []).append(e)
+    for name, stats in PROGRAM_SPANS.items():
+        assert name in by_name, name
+        assert all(stats <= set(e.stats) for e in by_name[name]), name
+    (score,) = by_name["repro.engine.score"]
+    assert score.stats["rows"] == 3 and score.stats["chunks"] == 1
+    assert score.stats["padded_tokens"] == 8 * by_name["repro.engine.score.prep"][0] \
+        .stats["width"]
+    (gen,) = by_name["repro.engine.generate"]
+    assert gen.stats["requests"] == 3
+    assert len(by_name["repro.sched.prefill"]) == 3
+    assert all(e.stats["slots"] == 2 and 1 <= e.stats["live"] <= 2
+               for e in by_name["repro.sched.decode"])
+    # the session span is named by its kind, with the sid as a stat
+    assert {e.stats["name"] for e in by_name["repro.session"]} \
+        == {e.stats["sid"] for e in by_name["repro.session"]}
+    assert not any(":" in e.name or "/" in e.name for e in events)
+
+
+def test_tracer_exports_are_unchanged_under_a_profile(tmp_path, monkeypatch):
+    left, right, world = _join_world(seed=5)
+
+    def traced():
+        tr = Tracer()
+        with T.activate(tr):
+            _pipeline(left, right, world).collect()
+        return tr
+
+    def stable(tr):
+        rows = [json.loads(json.dumps(s.as_dict(tr.origin))) for s in tr.spans()]
+        for r in rows:
+            for k in ("span_id", "parent_id", "ts_us", "dur_us", "thread"):
+                r.pop(k)
+            r["attrs"].pop("node_id", None)
+            r["attrs"].pop("wall_s", None)
+        summary = {k: {f: v for f, v in row.items() if f != "wall_s"}
+                   for k, row in tr.stage_summary().items()}
+        chrome = [(e["name"], e["cat"], e["ph"], sorted(e["args"]))
+                  for e in tr.chrome_trace()["traceEvents"]]
+        return sorted(map(json.dumps, rows)), summary, sorted(chrome)
+
+    plain = traced()
+    box = {}
+    events = _profiled_events(tmp_path, monkeypatch, lambda: box.setdefault("tr", traced()))
+    assert stable(box["tr"]) == stable(plain)
+    # and the same spans reached the profile
+    assert len([e for e in events if e.name == "repro.plan_stage"]) \
+        == len(plain.spans(kind="plan_stage"))
+
+
+def test_attrs_set_after_open_reach_the_profile(tmp_path, monkeypatch):
+    def body():
+        with T.span("a/b", "x", event="repro.test.late", n=1) as sp:
+            sp.set(m=2.5, flag=True, skipped=[1, 2])
+            sp.add("n", 2)
+        tr = Tracer()
+        with T.activate(tr), T.span("op", kind="operator", k="v") as sp:
+            sp.add("oracle_calls", 4)
+
+    events = {e.name: e for e in _profiled_events(tmp_path, monkeypatch, body)}
+    assert events["repro.test.late"].stats == {"n": 3, "m": 2.5, "flag": 1}
+    assert events["repro.operator"].stats == {"k": "v", "name": "op", "oracle_calls": 4}
+
+
+def test_kernel_span_under_a_profile_alone_does_not_sync(tmp_path, monkeypatch, rng):
+    q = rng.normal(size=(4, 16)).astype(np.float32)
+    c = rng.normal(size=(32, 16)).astype(np.float32)
+    seen = []
+    real = ops._ready
+    monkeypatch.setattr(ops, "_ready", lambda out, sp: seen.append(sp) or real(out, sp))
+    events = _profiled_events(tmp_path, monkeypatch, lambda: ops.similarity(q, c))
+    (sp,) = seen
+    assert sp is not None and not isinstance(sp, T.Span)   # no block_until_ready
+    (ev,) = [e for e in events if e.name == "repro.kernel.similarity"]
+    assert ev.stats["nq"] == 4 and ev.stats["nc"] == 32
