@@ -28,6 +28,38 @@ from repro.models import registry
 from repro.obs import trace as _trace
 
 
+_SCORE_ROWS = 32   # most rows in one scoring step
+
+
+def _score_chunks(lens: list[int], max_seq: int) -> list[tuple[int, np.ndarray]]:
+    """The rows of one scoring call, by their token lengths, as (width, row
+    indices) chunks of at most `_SCORE_ROWS` rows, narrowest first.
+
+    Rows are sorted by length (stable) and cut one of two ways: wherever the
+    width bucket changes and every 32 rows within a width, or every 32 rows
+    with the short remainder first.  The call takes the cut that pads fewer
+    tokens (row bucket x width bucket), the width cut on a tie.  The 32-row
+    cut has the chunk sizes of arrival order and never pads more than it, so
+    no call pads more than unsorted; the width cut pads less where a width's
+    rows fill their own row buckets."""
+    order = np.argsort(lens, kind="stable")
+    width = [min(_bucket(lens[j]), max_seq) for j in order]
+    grouped: list[int] = []
+    for i, w in enumerate(width):
+        if not grouped or w != width[i - 1] or i - grouped[-1] == _SCORE_ROWS:
+            grouped.append(i)
+    r = len(order) % _SCORE_ROWS
+    cut = [0] * (r > 0) + list(range(r, len(order), _SCORE_ROWS))
+
+    def spans(starts: list[int]) -> list[tuple[int, int]]:
+        return list(zip(starts, starts[1:] + [len(order)]))
+
+    def padded(starts: list[int]) -> int:
+        return sum(min(_bucket(e - s, 8), _SCORE_ROWS) * width[e - 1] for s, e in spans(starts))
+
+    return [(width[e - 1], order[s:e]) for s, e in spans(min(grouped, cut, key=padded))]
+
+
 @dataclasses.dataclass
 class EngineStats:
     lm_calls: int = 0
@@ -77,34 +109,39 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     def _last_logits(self, prompts: list[str]) -> np.ndarray:
-        """One forward pass; per-row log-probs after the last real token. [B, V].
+        """Per-row log-probs after the last real token, in the caller's row
+        order. [B, V].
 
-        Rows and widths are padded to power-of-two buckets (at least 8 rows,
-        16 tokens), so the scoring step compiles once per (rows, width)
-        bucket, not once per batch."""
+        Rows are sorted by token length and scored in chunks of at most 32
+        (`_score_chunks`), so a short prompt is not padded to a long
+        neighbour's width.  Rows and widths are padded to power-of-two
+        buckets (at least 8 rows, 16 tokens), so the scoring step compiles
+        once per (rows, width) bucket, not once per batch."""
         with _trace.span("engine/score", "engine", event="repro.engine.score",
                          rows=len(prompts)) as sp:
-            out = []
-            bs = 32
+            seqs = [TOKENIZER.encode(p)[: self.runner.max_seq] for p in prompts]
+            chunks = _score_chunks([len(s) for s in seqs], self.runner.max_seq)
+            out = np.empty((len(prompts), self.cfg.vocab_size), np.float32)
             tokens = padded = 0
-            for i in range(0, len(prompts), bs):
+            for width, idx in chunks:
                 with _trace.span("engine/score.prep", "engine",
                                  event="repro.engine.score.prep") as prep:
-                    chunk = [TOKENIZER.encode(p)[: self.runner.max_seq]
-                             for p in prompts[i:i + bs]]
-                    width = min(_bucket(max(len(s) for s in chunk)), self.runner.max_seq)
-                    rows = min(_bucket(len(chunk), 8), bs)
+                    chunk = [seqs[j] for j in idx]
+                    rows = min(_bucket(len(chunk), 8), _SCORE_ROWS)
                     toks = TOKENIZER.pad_batch(chunk + [[]] * (rows - len(chunk)), width)
                     last = np.asarray([max(len(s), 1) - 1 for s in chunk]
                                       + [0] * (rows - len(chunk)), np.int32)
                     prep.set(rows=len(chunk), width=width)
-                out.append(self.runner.logprobs(toks, last)[: len(chunk)])
+                out[idx] = self.runner.logprobs(toks, last)[: len(chunk)]
                 n = sum(len(s) for s in chunk)
                 self.stats.add(len(chunk), n, len(chunk))
                 tokens += n
                 padded += rows * width
-            sp.set(tokens=tokens, padded_tokens=padded, chunks=len(out))
-            return np.concatenate(out, axis=0)
+            widest = max((w for w, _ in chunks), default=0)
+            narrowed = sum(len(idx) for w, idx in chunks if w < widest)
+            sp.set(tokens=tokens, padded_tokens=padded, chunks=len(chunks),
+                   narrowed_rows=narrowed)
+            return out
 
     def predicate(self, prompts: list[str]) -> tuple[np.ndarray, np.ndarray]:
         """Returns (passes [B] bool, score [B]: p(True | {True,False}))."""

@@ -8,11 +8,12 @@ import pytest
 from repro.configs import get_smoke
 from repro.data.tokenizer import TOKENIZER
 from repro.engine import paged as paged_mod
-from repro.engine.engine import InferenceEngine
-from repro.engine.runner import ModelRunner
+from repro.engine.engine import InferenceEngine, _score_chunks
+from repro.engine.runner import ModelRunner, _bucket
 from repro.engine.sampler import Sampler
 from repro.engine.scheduler import ContinuousBatchScheduler, Request
 from repro.models import registry
+from repro.obs import trace
 
 
 @pytest.fixture(scope="module")
@@ -139,6 +140,120 @@ def test_scoring_path_matches_full_forward_at_last_token(small_engine):
     for n in (3, 5, 8, 9):
         eng._last_logits(same_len[:n])
     assert eng.runner._score._cache_size() - n0 <= 2
+
+
+def _straddling_prompts(n_short: int, n_long: int, seed: int) -> list[str]:
+    """Prompts of 20-32 tokens (the 32-token width bucket) and of 33-60 tokens
+    (the 64-token one), shuffled."""
+    rng = np.random.default_rng(seed)
+    lens = [int(rng.integers(20, 33)) for _ in range(n_short)] \
+        + [int(rng.integers(33, 61)) for _ in range(n_long)]
+    prompts = []
+    for i, n in enumerate(lens):
+        p = f"row {i} claim"
+        p += " x" * ((n - len(TOKENIZER.encode(p))) // 2)
+        prompts.append(p + "?" * (n - len(TOKENIZER.encode(p))))
+    assert [len(TOKENIZER.encode(p)) for p in prompts] == lens
+    return [prompts[i] for i in rng.permutation(len(prompts))]
+
+
+def test_scoring_sorts_rows_by_length_and_keeps_the_callers_order(small_engine):
+    """A shuffled call of mixed lengths across a width boundary is scored in
+    length-sorted chunks of one width each: short rows run at the narrow
+    width, fewer tokens are padded than at the widest width, and every row
+    still equals the full teacher-forced forward at its last token, in the
+    caller's order."""
+    eng = small_engine
+    prompts = _straddling_prompts(36, 12, seed=3)
+    seqs = [TOKENIZER.encode(p) for p in prompts]
+    tr = trace.Tracer()
+    with trace.activate(tr):
+        got = eng._last_logits(prompts)
+    assert got.shape == (len(prompts), eng.cfg.vocab_size)
+    by_len: dict[int, list[int]] = {}
+    for i, s in enumerate(seqs):
+        by_len.setdefault(len(s), []).append(i)
+    for rows in by_len.values():
+        toks = jnp.asarray([seqs[i] for i in rows], jnp.int32)
+        logits, _ = registry.forward(eng.cfg, eng.runner.params, toks)
+        want = jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), axis=-1)
+        np.testing.assert_allclose(got[rows], np.asarray(want), atol=1e-4)
+    (score,) = [s for s in tr.spans() if s.name == "engine/score"]
+    widest = _bucket(max(len(s) for s in seqs))
+    assert widest == 64
+    assert score.attrs["rows"] == 48 and score.attrs["chunks"] == 3
+    assert score.attrs["tokens"] == sum(len(s) for s in seqs)
+    assert score.attrs["padded_tokens"] == 32 * 32 + 8 * 32 + 16 * 64 < len(prompts) * widest
+    assert score.attrs["narrowed_rows"] == 36
+    preps = [s for s in tr.spans() if s.name == "engine/score.prep"]
+    assert [(s.attrs["rows"], s.attrs["width"]) for s in preps] == [(32, 32), (4, 32), (12, 64)]
+
+
+def _arrival_padded(lens: list[int], max_seq: int) -> int:
+    """Tokens padded when the call is cut every 32 rows in arrival order."""
+    return sum(min(_bucket(len(c), 8), 32) * min(_bucket(max(c)), max_seq)
+               for c in (lens[i:i + 32] for i in range(0, len(lens), 32)))
+
+
+def test_small_call_across_a_width_pads_no_more_than_arrival_order(small_engine):
+    """Two rows on either side of a width boundary stay one chunk at the
+    wider width: splitting them by width would pad 8x32 + 8x64 tokens
+    against arrival order's 8x64, and dispatch twice."""
+    eng = small_engine
+    prompts = _straddling_prompts(1, 1, seed=7)
+    seqs = [TOKENIZER.encode(p) for p in prompts]
+    tr = trace.Tracer()
+    with trace.activate(tr):
+        got = eng._last_logits(prompts)
+    for i, s in enumerate(seqs):
+        logits, _ = registry.forward(eng.cfg, eng.runner.params, jnp.asarray([s], jnp.int32))
+        want = jax.nn.log_softmax(logits[0, -1].astype(jnp.float32))
+        np.testing.assert_allclose(got[i], np.asarray(want), atol=1e-4)
+    (score,) = [s for s in tr.spans() if s.name == "engine/score"]
+    assert score.attrs["chunks"] == 1 and score.attrs["narrowed_rows"] == 0
+    assert score.attrs["padded_tokens"] == 8 * 64 == _arrival_padded(
+        [len(s) for s in seqs], eng.runner.max_seq)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_score_chunks_never_pad_more_than_arrival_order(seed):
+    """Over random calls of 1-140 rows with lengths across several width
+    buckets, the chunks hold every row once, at most 32 rows each, narrowest
+    first at the bucket of their longest row, and pad no more tokens than
+    cutting the call every 32 rows unsorted."""
+    rng = np.random.default_rng(seed)
+    for _ in range(300):
+        lens = [int(n) for n in rng.integers(1, 600, size=int(rng.integers(1, 141)))]
+        chunks = _score_chunks(lens, 512)
+        rows = np.concatenate([idx for _, idx in chunks])
+        assert sorted(rows.tolist()) == list(range(len(lens)))
+        assert all(1 <= len(idx) <= 32 for _, idx in chunks)
+        assert [w for w, _ in chunks] == sorted(w for w, _ in chunks)
+        assert all(w == min(_bucket(max(lens[j] for j in idx)), 512) for w, idx in chunks)
+        padded = sum(min(_bucket(len(idx), 8), 32) * w for w, idx in chunks)
+        assert padded <= _arrival_padded(lens, 512)
+
+
+def test_warm_up_widths_cover_length_sorted_calls():
+    """Compiling the scoring step as the benchmark's warm-up does (each
+    width bucket the prompts reach, at 8, 16 and 32 rows) covers every
+    shuffled mixed-length call: sorting rows into chunks adds no program."""
+    cfg = get_smoke("llama3.2-3b").with_(vocab_size=TOKENIZER.vocab_size, num_layers=1,
+                                         d_model=32, d_ff=64)
+    eng = InferenceEngine(cfg, max_slots=2, max_seq=128)
+    probes = _straddling_prompts(8, 8, seed=5)
+    by_width: dict[int, list[str]] = {}
+    for p in probes:
+        by_width.setdefault(_bucket(len(TOKENIZER.encode(p))), []).append(p)
+    assert sorted(by_width) == [32, 64]
+    for base in by_width.values():
+        for rows in (8, 16, 32):
+            eng._last_logits([base[i % len(base)] for i in range(rows)])
+    n0 = eng.runner._score._cache_size()
+    assert n0 == 6
+    for seed, (n_short, n_long) in enumerate([(36, 12), (5, 60), (40, 3), (1, 1), (70, 30)]):
+        eng._last_logits(_straddling_prompts(n_short, n_long, seed=seed))
+    assert eng.runner._score._cache_size() == n0
 
 
 class _BrokenRunner:

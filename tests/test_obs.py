@@ -464,7 +464,7 @@ PROGRAM_SPANS = {
     "repro.dispatch.oracle.predicate": {"fused_calls", "unique_prompts", "backend_prompts",
                                         "store_hits", "wait_ms_sum", "wait_ms_max"},
     "repro.dispatch.oracle.generate": {"fused_calls", "wait_ms_sum", "wait_ms_max"},
-    "repro.engine.score": {"rows", "tokens", "padded_tokens", "chunks"},
+    "repro.engine.score": {"rows", "tokens", "padded_tokens", "chunks", "narrowed_rows"},
     "repro.engine.score.prep": {"rows", "width"},
     "repro.runner.fetch": set(),
     "repro.engine.generate": {"requests"},
