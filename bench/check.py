@@ -1,5 +1,5 @@
 """Whether the timed path answered correctly: what the window produced,
-against the configuration's plain float32 reference.
+against the plain float32 reference that each role's configuration names.
 
 Every number is compared with the limit the configuration file gives it:
 
@@ -29,11 +29,11 @@ holds it to the same limits: it has to fail one of them.  The benchmark's
 own runs never call it."""
 from __future__ import annotations
 
-import importlib
 import re
 
 import numpy as np
 
+from bench import configs
 from bench.weights import FALSE_ID, TRUE_ID
 
 BOS, PAD, EOS = 257, 256, 258
@@ -66,10 +66,6 @@ def decode(ids) -> str:
     if buf:
         out.append(bytes(buf).decode("utf-8", errors="replace"))
     return "".join(out)
-
-
-def reference_module(cfg: dict):
-    return importlib.import_module(f"bench.configs.{cfg['reference']}")
 
 
 def block_inputs(seqs: list, max_seq: int, positions: list | None = None):
@@ -110,11 +106,11 @@ def _sample(rng, n: int, k: int, longest: int) -> list[int]:
     return [longest] + sorted(rest[int(j)] for j in pick)
 
 
-def _label_logprobs(cfg, weights, rcfg, prompts, max_seq, *, control):
-    """The reference's log-probs of the two label tokens [n, 2] after each
-    prompt."""
+def _label_logprobs(weights, rcfg, prompts, max_seq, *, control):
+    """The log-probs of the two label tokens [n, 2] after each prompt, from
+    the reference module that the role's configuration ``rcfg`` names."""
     seqs = [encode(p)[:max_seq] for p in prompts]
-    lg = _logits(reference_module(cfg), weights, rcfg, seqs, [[len(s) - 1] for s in seqs],
+    lg = _logits(configs.reference(rcfg), weights, rcfg, seqs, [[len(s) - 1] for s in seqs],
                  max_seq, control=control)
     return _logsoftmax(np.stack([x[0] for x in lg]))[:, [TRUE_ID, FALSE_ID]]
 
@@ -165,11 +161,11 @@ def _filter_readings(cfg, weights, kept, queries, rng, max_seq, *, control, info
             if ret:
                 take = rng.choice(len(ret), size=min(MAX_EXTRA, len(ret)), replace=False)
                 extra = [by_id[ret[int(j)]] for j in sorted(take)]
-        ref = _label_logprobs(cfg, weights[role], roles[role], prompts + extra, max_seq,
+        ref = _label_logprobs(weights[role], roles[role], prompts + extra, max_seq,
                               control=False)
         if control:
-            low = _label_logprobs(cfg, weights[role], roles[role], prompts + extra,
-                                  max_seq, control=True)
+            low = _label_logprobs(weights[role], roles[role], prompts + extra, max_seq,
+                                  control=True)
             served = low[:len(prompts)]
         else:
             served = np.asarray([[rows[i][1], rows[i][2]] for i in pick])
@@ -192,7 +188,7 @@ def _filter_readings(cfg, weights, kept, queries, rng, max_seq, *, control, info
         if control:     # calibration runs also read the selectivity the rows had
             every = kept["scored"]["proxy"]
             take = rng.choice(len(every), size=min(SAMPLE_ROWS, len(every)), replace=False)
-            lp = _label_logprobs(cfg, weights[role], roles[role],
+            lp = _label_logprobs(weights[role], roles[role],
                                  [every[int(j)][0] for j in take], max_seq, control=False)
             info["uniform_true_share"] = float(np.mean(lp[:, 0] > lp[:, 1]))
             info["uniform_margin_sd"] = float(np.std(lp[:, 0] - lp[:, 1]))
@@ -228,7 +224,7 @@ def _map_readings(cfg, weights, kept, queries, rng, max_seq, *, control):
         seqs.append(s["prompt"] + s["served"][:-1])
         positions.append([len(s["prompt"]) - 1 + k for k in range(len(s["served"]))])
         served.append(s["served"])
-    ref_mod = reference_module(cfg)
+    ref_mod = configs.reference(cfg)
     ref = _logits(ref_mod, weights["oracle"], cfg, seqs, positions, max_seq, control=False)
     if control:
         low = _logits(ref_mod, weights["oracle"], cfg, seqs, positions, max_seq, control=True)

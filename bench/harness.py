@@ -2,8 +2,9 @@
 the window, drain, check the answers against the plain reference, print.
 
 Everything that belongs to one cell is found by name: the configuration in
-``bench/configs/<config>.json`` (with its reference module beside it), the
-traffic mix in ``bench/mixes/<mix>.json``, each per-layer metric's reader in
+``bench/configs/<config>.json`` (beside it the architecture and reference
+modules it names: ``bench/configs/__init__.py``), the traffic mix in
+``bench/mixes/<mix>.json``, each per-layer metric's reader in
 ``bench/metrics/<metric>.py`` (or the file of the part of its name before
 the first dot).  Adding a cell, a mix or a metric adds files and entries;
 it edits none of the harness."""
@@ -21,7 +22,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import check, traffic
+from bench import check, configs, traffic
 from bench import weights as W
 
 BENCH = Path(__file__).resolve().parent
@@ -83,16 +84,9 @@ def reader(metric: str):
 
 
 def program_config(cfg: dict):
-    """The system's ``ModelConfig`` for a configuration file's sizes."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense", num_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], head_dim=cfg.get("head_dim", 0),
-        qkv_bias=bool(cfg["qkv_bias"]), rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]), dtype="bfloat16")
+    """The system's ``ModelConfig`` for a configuration file's sizes, from
+    the configuration's architecture module."""
+    return configs.architecture(cfg).program_config(cfg)
 
 
 class CompileClock:
@@ -147,13 +141,13 @@ class Recorder:
     calls: records what each produced (label log-probs per prompt; the
     tokens each request was served) and, while tracing, opens a
     ``bench.<role>.<call>`` annotation around each with its rows, tokens and
-    operations.  The wrapped calls return exactly what they returned."""
+    operations (counted by the role's architecture module).  The wrapped
+    calls return exactly what they returned."""
 
     def __init__(self, role: str, engine, cfg: dict, *, max_new: int):
-        from bench import flops
         self.role, self.engine, self.cfg, self.max_new = role, engine, cfg, max_new
         self.annotate = False
-        self.flops = flops
+        self.arch = configs.architecture(cfg)
         self.scored: list[tuple[str, float, float]] = []
         self.generated: list[dict] = []
         self._slots: dict[int, dict] = {}
@@ -173,7 +167,7 @@ class Recorder:
 
     def _last_logits(self, prompts):
         lens = [min(len(p.encode()) + 1, self.engine.runner.max_seq) for p in prompts]
-        fl = sum(self.flops.sequence_flops(self.cfg, n) for n in lens)
+        fl = sum(self.arch.sequence_flops(self.cfg, n) for n in lens)
         with _annotate(self.annotate, f"bench.{self.role}.score", rows=len(prompts),
                        tokens=sum(lens), flops=fl):
             out = self._orig[0](prompts)
@@ -184,7 +178,7 @@ class Recorder:
     def _prefill(self, tokens, slot, extra=None):
         n = int(len(tokens))
         with _annotate(self.annotate, f"bench.{self.role}.prefill", tokens=n,
-                       flops=self.flops.sequence_flops(self.cfg, n)):
+                       flops=self.arch.sequence_flops(self.cfg, n)):
             logits = self._orig[1](tokens, slot, extra=extra)
         seq = {"prompt": [int(t) for t in tokens], "served": []}
         self._slots[int(slot)] = seq
@@ -194,7 +188,7 @@ class Recorder:
 
     def _decode(self, tokens, lens):
         live = [s for s, q in self._slots.items() if self._open(q, tokens, lens, s)]
-        fl = sum(self.flops.decode_flops(self.cfg, int(lens[s]) + 1) for s in live)
+        fl = sum(self.arch.decode_flops(self.cfg, int(lens[s]) + 1) for s in live)
         with _annotate(self.annotate, f"bench.{self.role}.decode", slots=len(live), flops=fl):
             logits = self._orig[2](tokens, lens)
         self._pending = ("decode", live)
@@ -312,7 +306,7 @@ def _shape_labels(weights: dict, cfg: dict, mix: dict, seed: int, max_seq: int) 
     per = LABEL_ROWS // LABEL_QUERIES
     rows = [r for _ in range(LABEL_QUERIES) for r in gen.query().records[:per]]
     seqs = [check.encode(p)[:max_seq] for p in _predicate_prompts(mix, rows)]
-    ref = check.reference_module(cfg)
+    ref = configs.reference(cfg)
     hidden = []
     for i in range(0, len(seqs), check.BLOCK):
         toks, at = check.block_inputs(seqs[i:i + check.BLOCK], max_seq)
@@ -364,6 +358,9 @@ def build(cell: Cell, seed: int, *, trace: bool, fault=None) -> Built:
     roles = {"oracle": cfg}
     if mix["operator"] == "filter_cascade":
         roles["proxy"] = cfg["proxy"]
+    for rcfg in roles.values():    # a module left unnamed stops the run before any draw
+        configs.architecture(rcfg)
+        configs.reference(rcfg)
     drawn, engines, recorders = {}, {}, {}
     for i, (role, rcfg) in enumerate(roles.items()):
         drawn[role] = W.draw(rcfg, seed, salt=i + 1)

@@ -1,8 +1,8 @@
 """The whole model step's share of the chip's peak bf16 rate, in percent:
-the operations the served tokens need (``bench.flops``: real tokens, causal
-attention, the unembedding of read positions), carried on the harness's
-scoring, prefill and decode spans, over the traced window's length times the
-peak.  A span that crosses an edge of the window counts for the share of its
+the operations the served tokens need (the role's architecture module;
+for ``dense``, ``bench.flops``: real tokens, causal attention, the
+unembedding of read positions), carried on the harness's scoring, prefill
+and decode spans, over the traced window's length times the peak.  A span that crosses an edge of the window counts for the share of its
 length inside it (one scoring span covers a whole fused batch, seconds
 long)."""
 

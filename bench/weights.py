@@ -1,10 +1,11 @@
 """Random weights for one configuration, drawn on the device in one jitted
 call from the run's seed, in the dtype they are served in.
 
-The tree has the key layout the system's dense decoder takes (stacked over
-layers); ``check_layout`` holds it against the system's own parameter table
-before the weights are handed over, so a layout change fails loudly instead
-of serving the wrong tensors.  The same tree feeds the plain reference."""
+The tree has the key layout the system takes for the configuration's
+architecture (its module's ``leaf_plan``); ``check_layout`` holds it against
+the system's own parameter table before the weights are handed over, so a
+layout change fails loudly instead of serving the wrong tensors.  The same
+tree feeds the plain reference."""
 from __future__ import annotations
 
 import functools
@@ -14,39 +15,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.configs.dense_reference import dims
+from bench import configs
 
 TRUE_ID, FALSE_ID = 259, 260      # the byte tokenizer's label tokens
 
 
 def leaf_plan(cfg: dict) -> dict:
-    """path -> (shape, dtype, init, std).  ``init`` is ``normal`` (std * N),
+    """path -> (shape, dtype, init, std) of every parameter, from the
+    configuration's architecture module.  ``init`` is ``normal`` (std * N),
     ``one_plus`` (1 + std * N) or ``zeros``."""
-    dm = dims(cfg)
-    d, h, hk, hd, f, V, L = (dm[k] for k in ("d", "h", "hk", "hd", "f", "V", "L"))
-    bf, f32 = "bfloat16", "float32"
-    plan = {
-        # a tied table is also the head: scale it like one, so logits stay
-        # near unit size (std 1 here gave logits of std sqrt(d) in the proxy)
-        ("embed", "embedding"): ((V, d), bf, "normal", d ** -0.5 if dm["tied"] else 1.0),
-        ("layers", "attn", "wq"): ((L, d, h, hd), bf, "normal", d ** -0.5),
-        ("layers", "attn", "wk"): ((L, d, hk, hd), bf, "normal", d ** -0.5),
-        ("layers", "attn", "wv"): ((L, d, hk, hd), bf, "normal", d ** -0.5),
-        ("layers", "attn", "wo"): ((L, h, hd, d), bf, "normal", (h * hd) ** -0.5),
-        ("layers", "attn_norm", "scale"): ((L, d), f32, "one_plus", 0.1),
-        ("layers", "ffn_norm", "scale"): ((L, d), f32, "one_plus", 0.1),
-        ("layers", "ffn", "w_gate"): ((L, d, f), bf, "normal", d ** -0.5),
-        ("layers", "ffn", "w_up"): ((L, d, f), bf, "normal", d ** -0.5),
-        ("layers", "ffn", "w_down"): ((L, f, d), bf, "normal", f ** -0.5),
-        ("final_norm", "scale"): ((d,), f32, "one_plus", 0.1),
-    }
-    if dm["bias"]:
-        plan[("layers", "attn", "bq")] = ((L, h, hd), f32, "normal", 0.1)
-        plan[("layers", "attn", "bk")] = ((L, hk, hd), f32, "normal", 0.1)
-        plan[("layers", "attn", "bv")] = ((L, hk, hd), f32, "normal", 0.1)
-    if not dm["tied"]:
-        plan[("out", "head")] = ((d, V), bf, "normal", d ** -0.5)
-    return plan
+    return configs.architecture(cfg).leaf_plan(cfg)
 
 
 def _nest(flat: dict) -> dict:
